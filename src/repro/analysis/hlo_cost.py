@@ -195,7 +195,7 @@ def analyze_compiled(compiled, detail: bool = False) -> HloCost:
 
 
 def xla_reported_cost(compiled) -> dict:
-    """XLA's own cost_analysis as a flat dict on every JAX version.
+    """XLA's own cost_analysis as a flat dict.
 
     These are the *raw* numbers (scan bodies counted once — see module
     docstring); ``analyze_compiled`` is the trip-count-corrected view.

@@ -7,7 +7,7 @@ Three terms per (arch × shape × mesh), per the assignment:
     collective = coll_bytes     / (chips * 50e9    B/s/link ICI)
 
 HLO_FLOPs / HLO_bytes come from XLA's cost analysis, read through
-compat.normalized_cost_analysis (dict on every JAX version). Collective bytes are
+compat.normalized_cost_analysis (a flat dict). Collective bytes are
 NOT in cost_analysis: `collective_bytes` parses the optimized HLO text and sums
 *operand* bytes of every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute op (per-type breakdown kept for diagnosis).

@@ -30,8 +30,6 @@ from typing import Any
 import jax
 import numpy as np
 
-from repro.compat import tree_flatten_with_path
-
 
 class CheckpointError(RuntimeError):
     """A checkpoint is unreadable: missing, truncated, corrupt or mismatched.
@@ -45,7 +43,7 @@ def _flatten(tree: Any):
     leaves, treedef = jax.tree.flatten(tree)
     paths = [
         "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        for path, _ in tree_flatten_with_path(tree)[0]
+        for path, _ in jax.tree.flatten_with_path(tree)[0]
     ]
     return leaves, paths, treedef
 

@@ -1,26 +1,15 @@
-"""JAX version-portability layer.
+"""Thin forwards over the mesh and cost-analysis surface of the installed JAX.
 
-The single place the repo touches version-sensitive JAX surface area; every
-other module imports these shims instead of the raw APIs (enforced by grep in
-the acceptance criteria and exercised on both branches by tests/test_compat.py):
-
-* ``make_mesh(shape, axes)``          — Mesh construction (axis_types vs 0.4.x)
-* ``set_mesh(mesh)``                  — ambient-mesh context manager
-* ``current_mesh()``                  — ambient-mesh lookup (get_abstract_mesh
-                                        vs the 0.4.x thread-local mesh)
-* ``current_mesh_axis_sizes()``       — {axis: size} of the ambient mesh
-* ``shard_map(...)``                  — new-style signature everywhere
-* ``lax_map_batched(f, xs, batch_size=)`` — lax.map chunking (kwarg vs manual)
-* ``normalized_cost_analysis(c)``     — flat-dict cost metrics everywhere
-* ``VERSION_FEATURES`` / ``detect_features()`` / ``describe()`` — capability table
+* ``make_mesh(shape, axes)``    — Mesh with all-auto axis types (GSPMD places)
+* ``set_mesh(mesh)``            — ambient-mesh context manager
+* ``current_mesh()``            — the ambient mesh, or None outside any mesh
+* ``current_mesh_axis_sizes()`` — {axis: size} of the ambient mesh
+* ``shard_map(...)``            — ``jax.shard_map`` with the manual axes checked
+* ``normalized_cost_analysis(c)`` — flat cost-metric dict of a compiled program
 """
-from repro.compat.control import lax_map_batched
 from repro.compat.mesh import make_mesh, set_mesh
-from repro.compat.pallas import tpu_compiler_params
 from repro.compat.sharding import current_mesh, current_mesh_axis_sizes, shard_map
-from repro.compat.tree import tree_flatten_with_path
-from repro.compat.version import VERSION_FEATURES, describe, detect_features
-from repro.compat.xla import normalize_cost_result, normalized_cost_analysis
+from repro.compat.xla import normalized_cost_analysis
 
 __all__ = [
     "make_mesh",
@@ -28,12 +17,5 @@ __all__ = [
     "current_mesh",
     "current_mesh_axis_sizes",
     "shard_map",
-    "lax_map_batched",
-    "tpu_compiler_params",
-    "tree_flatten_with_path",
     "normalized_cost_analysis",
-    "normalize_cost_result",
-    "VERSION_FEATURES",
-    "detect_features",
-    "describe",
 ]

@@ -1,16 +1,9 @@
-"""Version-portable mesh construction and ambient-mesh installation.
+"""Mesh construction and ambient-mesh installation.
 
-* ``make_mesh(shape, axes)`` — the one way this repo builds a Mesh.
-  On JAX >= 0.6 it forwards ``axis_types=(AxisType.Auto,) * len(axes)`` so the
-  mesh is explicitly all-auto (GSPMD decides placement); on 0.4.x, where
-  ``AxisType`` does not exist and every mesh axis is implicitly auto, it calls
-  plain ``jax.make_mesh`` (0.4.35+) or falls back to
-  ``Mesh(mesh_utils.create_device_mesh(shape), axes)``.
-
-* ``set_mesh(mesh)`` — context manager installing ``mesh`` as the ambient mesh
-  (so bare-``PartitionSpec`` sharding constraints resolve against it). Prefers
-  ``jax.set_mesh`` / ``jax.sharding.use_mesh``; on 0.4.x a ``Mesh`` is its own
-  context manager and installs itself into the thread-local physical mesh.
+* ``make_mesh(shape, axes)`` — the one way this repo builds a Mesh: every axis
+  is ``AxisType.Auto``, so GSPMD decides placement.
+* ``set_mesh(mesh)`` — context manager installing ``mesh`` as the ambient
+  mesh (bare-``PartitionSpec`` sharding constraints resolve against it).
 """
 from __future__ import annotations
 
@@ -18,36 +11,15 @@ from typing import Sequence
 
 import jax
 
-from repro.compat import version as _v
-
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None) -> jax.sharding.Mesh:
-    """Build a Mesh with all-auto axis types on any supported JAX version."""
+    """Build a Mesh with all-auto axis types."""
     shape = tuple(shape)
     axes = tuple(axes)
     if len(shape) != len(axes):
         raise ValueError(f"mesh shape {shape} / axis names {axes} length mismatch")
-    if _v.has_axis_type() and _v.make_mesh_takes_axis_types():
-        types = (jax.sharding.AxisType.Auto,) * len(axes)
-        if devices is not None:
-            return jax.make_mesh(shape, axes, axis_types=types, devices=devices)
-        return jax.make_mesh(shape, axes, axis_types=types)
-    if _v.has_make_mesh():
-        if devices is not None:
-            return jax.make_mesh(shape, axes, devices=devices)
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-
-    if devices is None:
-        # jax.make_mesh uses a prefix of jax.devices() for sub-meshes;
-        # create_device_mesh insists on an exact device count — match the
-        # prefix behavior so small (e.g. (1, 1)) test meshes build anywhere.
-        n = 1
-        for s in shape:
-            n *= s
-        devices = jax.devices()[:n]
-    devs = mesh_utils.create_device_mesh(shape, devices=devices)
-    return jax.sharding.Mesh(devs, axes)
+    types = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=types, devices=devices)
 
 
 def set_mesh(mesh: jax.sharding.Mesh):
@@ -57,9 +29,4 @@ def set_mesh(mesh: jax.sharding.Mesh):
     ``compat.current_mesh()`` returns it and bare-PartitionSpec
     ``with_sharding_constraint`` resolves against it.
     """
-    if _v.has_set_mesh():
-        return jax.set_mesh(mesh)
-    if _v.has_use_mesh():
-        return jax.sharding.use_mesh(mesh)
-    # 0.4.x: Mesh.__enter__ installs the thread-local physical mesh.
-    return mesh
+    return jax.set_mesh(mesh)

@@ -1,23 +1,16 @@
-"""Version-portable ambient-mesh lookup and shard_map.
+"""Ambient-mesh lookup and shard_map.
 
 * ``current_mesh()`` — the mesh installed by ``compat.set_mesh`` (or any mesh
-  context manager), or None when there is none. Prefers
-  ``jax.sharding.get_abstract_mesh``; on 0.4.x reads the thread-local physical
-  mesh that the ``Mesh`` context manager sets.
-
+  context manager), or None when there is none.
 * ``shard_map(f, mesh=..., in_specs=..., out_specs=..., axis_names=...,
-  check_vma=...)`` — the new-style (>= 0.6) ``jax.shard_map`` signature. On
-  0.4.x it translates to ``jax.experimental.shard_map.shard_map``:
-  ``axis_names`` (the *manual* axes) becomes its complement ``auto=``, and
-  ``check_vma`` maps to the old name ``check_rep``.
+  check_vma=...)`` — ``jax.shard_map``; ``axis_names`` (the *manual* axes,
+  default all) must name axes of ``mesh``.
 """
 from __future__ import annotations
 
 from typing import Callable, Set
 
 import jax
-
-from repro.compat import version as _v
 
 
 def current_mesh():
@@ -26,14 +19,7 @@ def current_mesh():
     Never raises on empty/absent meshes — callers treat None as "no mesh":
     sharding constraints become no-ops.
     """
-    if _v.has_get_abstract_mesh():
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return None
-        return mesh
-    from jax._src import mesh as _mesh_lib  # 0.4.x thread-local mesh context
-
-    mesh = _mesh_lib.thread_resources.env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return None
     return mesh
@@ -56,31 +42,17 @@ def shard_map(
     axis_names: Set[str] | None = None,
     check_vma: bool = False,
 ) -> Callable:
-    """New-style shard_map on every supported JAX version.
-
-    axis_names: the mesh axes that are *manual* inside `f` (default: all).
-    check_vma: varying-mesh-axes checking (old name: check_rep).
-    """
+    """``jax.shard_map`` with the manual axes (default: all) checked against
+    the mesh."""
     names = frozenset(mesh.axis_names if axis_names is None else axis_names)
     unknown = names - frozenset(mesh.axis_names)
     if unknown:
         raise ValueError(f"axis_names {sorted(unknown)} not in mesh axes {mesh.axis_names}")
-    if _v.has_top_level_shard_map():
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names=set(names),
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=check_vma,
-        auto=frozenset(mesh.axis_names) - names,
+        axis_names=set(names),
+        check_vma=check_vma,
     )

@@ -1,51 +1,10 @@
-"""Version-portable view of XLA's compiled-program cost analysis.
-
-``Compiled.cost_analysis()`` changed shape across JAX versions:
-
-* 0.4.x returns a *list* of per-program property dicts (usually length 1;
-  multi-program executables produce one dict per program);
-* newer JAX returns a single flat dict.
-
-``normalized_cost_analysis`` canonicalizes both (plus a None result from
-backends without cost modeling) into one flat ``{metric: float}`` dict, so
-callers can always do ``cost["flops"]`` / ``cost.get("bytes accessed")``.
-Dispatch is on the actual returned value, not the JAX version, so the shim
-also survives backends that diverge from their pin's default.
-"""
+"""Flat view of XLA's compiled-program cost analysis."""
 from __future__ import annotations
 
-from typing import Any, Mapping
+from typing import Any
 
 
 def normalized_cost_analysis(compiled: Any) -> dict:
-    """Canonical flat dict of XLA cost metrics for a compiled program.
-
-    Accepts anything with a ``cost_analysis()`` method (``jax.stages.Compiled``).
-    Multi-program lists are merged by summing numeric values per key — the
-    total cost of executing every program once.
-    """
-    cost = compiled.cost_analysis()
-    return normalize_cost_result(cost)
-
-
-def normalize_cost_result(cost: Any) -> dict:
-    """Canonicalize a raw cost_analysis() return value (see module docstring)."""
-    if cost is None:
-        return {}
-    if isinstance(cost, Mapping):
-        return dict(cost)
-    if isinstance(cost, (list, tuple)):
-        dicts = [c for c in cost if isinstance(c, Mapping)]
-        if not dicts:
-            return {}
-        if len(dicts) == 1:
-            return dict(dicts[0])
-        merged: dict = {}
-        for d in dicts:
-            for k, v in d.items():
-                if isinstance(v, (int, float)) and isinstance(merged.get(k, 0.0), (int, float)):
-                    merged[k] = merged.get(k, 0.0) + v
-                else:
-                    merged.setdefault(k, v)
-        return merged
-    raise TypeError(f"unrecognized cost_analysis() result type: {type(cost).__name__}")
+    """``compiled.cost_analysis()`` as a fresh flat ``{metric: float}`` dict;
+    ``{}`` from a backend without cost modelling."""
+    return dict(compiled.cost_analysis() or {})

@@ -28,8 +28,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 N_PHASES = 8  # 45-degree discretization (Sec. IV)
 
 
@@ -206,9 +204,7 @@ def _score_assignments(h, phase_idx_batch, maj, n0, method):
         y = rx_constellations(h, pi)
         ber, _ = decision_metrics(y, maj, n0, method)
         return jnp.mean(ber)
-    # batch_size= chunking only exists on newer 0.4.x pins — compat falls back
-    # to a manual scan-of-vmap with identical results.
-    return compat.lax_map_batched(one, phase_idx_batch, batch_size=256)
+    return jax.lax.map(one, phase_idx_batch, batch_size=256)
 
 
 def optimize_phases_exhaustive(

@@ -275,7 +275,7 @@ def majority_allreduce(
 
 
 def sign_allreduce(
-    x: jax.Array, axis_name: str, *, key=None, ber=None, device_index=None
+    x: jax.Array, axis_name: str, *, key=None, ber=None
 ) -> jax.Array:
     """Majority-vote sign aggregation (1-bit compressed all-reduce) for floats.
 
@@ -283,25 +283,16 @@ def sign_allreduce(
     signSGD aggregation [Bernstein et al.] — structurally identical to the
     paper's OTA bundling with gradients in place of query hypervectors. Optional
     BER applies the OTA channel to the result (sign flips), which HDC-style error
-    tolerance (and signSGD's) absorbs.
-
-    `device_index`: this device's linear index along the reduce axes, used to
-    decorrelate the per-receiver noise. Callers inside a *partially-auto*
-    shard_map (the sign_majority trainer) must pass it explicitly (threaded in
-    as a sharded iota input): `lax.axis_index` there lowers to a partition-id
-    HLO op that 0.4.x XLA's SPMD partitioner rejects. Fully-manual bodies may
-    omit it and get the `lax.axis_index` fold, which is fine on every pin.
+    tolerance (and signSGD's) absorbs; each receiver folds its index along
+    the reduce axes into the key, so the flips are independent per device.
     """
     votes = jax.lax.psum(jnp.sign(x).astype(jnp.float32), axis_name)
     out = jnp.sign(votes)
     if ber is not None:
         assert key is not None, "OTA noise needs a PRNG key"
-        if device_index is not None:
-            key = jax.random.fold_in(key, device_index)
-        else:
-            axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-            for ax in axes:
-                key = jax.random.fold_in(key, jax.lax.axis_index(ax))
+        axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
+        for ax in axes:
+            key = jax.random.fold_in(key, jax.lax.axis_index(ax))
         flips = jax.random.bernoulli(key, ber, out.shape)
         out = jnp.where(flips, -out, out)
     return out.astype(x.dtype)

@@ -17,6 +17,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _to_bf16(bits: jax.Array) -> jax.Array:
+    """uint8 {0,1} -> bf16 through int32: the TPU lowering has no direct
+    uint8 -> bfloat16 cast."""
+    return bits.astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)
+
+
 def _assoc_kernel(q_ref, p_ref, o_ref, acc_ref, *, nk: int, bk: int, k_actual: int):
     k = pl.program_id(2)
 
@@ -27,8 +33,8 @@ def _assoc_kernel(q_ref, p_ref, o_ref, acc_ref, *, nk: int, bk: int, k_actual: i
     # bipolar conversion with k-padding mask (pads contribute 0, not (-1)·(-1)=+1)
     kpos = k * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
     mask = (kpos < k_actual).astype(jnp.bfloat16)                      # [1, bk]
-    qb = (2.0 * q_ref[...].astype(jnp.bfloat16) - 1.0) * mask          # [bm, bk]
-    pb = (2.0 * p_ref[...].astype(jnp.bfloat16) - 1.0) * mask          # [bn, bk]
+    qb = (2.0 * _to_bf16(q_ref[...]) - 1.0) * mask                     # [bm, bk]
+    pb = (2.0 * _to_bf16(p_ref[...]) - 1.0) * mask                     # [bn, bk]
     acc_ref[...] += jax.lax.dot_general(
         qb, pb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )

@@ -9,6 +9,10 @@ TPU mapping notes:
 * uint32 bitwise XOR + population_count lower to the VPU; the [bq, bc, W] intermediate
   stays in VREGs/VMEM (bq=8, bc=128, W<=512 -> <=2 MiB).
 * last-dim block sizes are multiples of 128 lanes; bq rides the 8-sublane dimension.
+* the fused top-1 carries its running (min, argmin) in [G, B, 1] outputs with
+  (1, bq, 1) blocks: the TPU lowering refuses an output block whose last two
+  dims are neither (8, 128)-aligned nor the array's own dims, which a (1, bq)
+  block on a [G, B] array is.
 """
 from __future__ import annotations
 
@@ -17,8 +21,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+# Sorted-key buffer sentinel: strictly greater than every real key (real keys
+# are bounded by (d+1)*C < 2**31, checked by the caller), so padded classes and
+# already-extracted entries can never win a rank. Kept as a Python int —
+# a module-level jnp scalar would be captured as a constant by pallas_call.
+_KEY_SENTINEL = 2**31 - 1
+# padded class columns get this distance in the top-1 carry so they never
+# win; a Python int for the same reason
+_POISON = 2**30
 
 
 def _hamming_kernel(q_ref, p_ref, o_ref):
@@ -87,11 +99,19 @@ def _topk_banked_kernel(c_real: int, bc: int, q_ref, p_ref, val_ref, idx_ref):
     p = p_ref[0]  # [bc, W] uint32 — this bank's prototype tile
     x = jnp.bitwise_xor(q[:, None, :], p[None, :, :])        # [bq, bc, W]
     dist = jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=-1)
-    # classes beyond c_real are padding: poison them so they can never win
+    merge_top1(c_real, bc, j, dist, val_ref, idx_ref)
+
+
+def merge_top1(c_real: int, bc: int, j, dist, val_ref, idx_ref):
+    """Fold one [bq, bc] distance tile (class block j) into the running
+    (min, first argmin) carry held in the [1, bq, 1] output tiles. Classes at
+    or beyond ``c_real`` are padding and are poisoned so they never win."""
     col = j * bc + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
-    dist = jnp.where(col < c_real, dist, jnp.int32(2**30))
-    loc_v = jnp.min(dist, axis=-1)                           # [bq]
-    loc_i = j * bc + jnp.argmin(dist, axis=-1).astype(jnp.int32)
+    dist = jnp.where(col < c_real, dist, jnp.int32(_POISON))
+    loc_v = jnp.min(dist, axis=-1, keepdims=True)            # [bq, 1]
+    # first column attaining the minimum (argmin's tie order)
+    loc_i = jnp.min(jnp.where(dist == loc_v, col, jnp.int32(_KEY_SENTINEL)),
+                    axis=-1, keepdims=True)
 
     @pl.when(j == 0)
     def _init():
@@ -103,6 +123,38 @@ def _topk_banked_kernel(c_real: int, bc: int, q_ref, p_ref, val_ref, idx_ref):
         better = loc_v < val_ref[0]
         idx_ref[0] = jnp.where(better, loc_i, idx_ref[0])
         val_ref[0] = jnp.where(better, loc_v, val_ref[0])
+
+
+def top1_banked_call(kernel, q, protos, *, bq: int, bc: int, interpret: bool,
+                     scratch_shapes=(), q_memory_space=None):
+    """Launch a fused per-bank top-1 kernel over grid (G, B/bq, C/bc).
+
+    Shared by the packed (hamming) and index-list (sparse) top-1 kernels:
+    both stream class blocks through `merge_top1`'s carry. Returns
+    (min_dist, argmin), each [G, B] int32.
+    """
+    g, b = q.shape[0], q.shape[1]
+    c, w = protos.shape[1], protos.shape[2]
+    assert b % bq == 0 and c % bc == 0, (q.shape, protos.shape, bq, bc)
+    out = jax.ShapeDtypeStruct((g, b, 1), jnp.int32)
+    out_spec = pl.BlockSpec((1, bq, 1), lambda g, i, j: (g, i, 0))
+    val, idx = pl.pallas_call(
+        kernel,
+        grid=(g, b // bq, c // bc),
+        in_specs=[
+            pl.BlockSpec((1, bq, q.shape[2]), lambda g, i, j: (g, i, 0),
+                         memory_space=q_memory_space),
+            pl.BlockSpec((1, bc, w), lambda g, i, j: (g, j, 0)),
+        ],
+        out_specs=[out_spec, out_spec],
+        out_shape=[out, out],
+        scratch_shapes=list(scratch_shapes),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+    )(q, protos)
+    return val[..., 0], idx[..., 0]
 
 
 @functools.partial(jax.jit, static_argnames=("c_real", "bq", "bc", "interpret"))
@@ -128,37 +180,10 @@ def hamming_topk_banked_pallas(
     g, b, w = q.shape
     g2, c, w2 = protos.shape
     assert g == g2 and w == w2, (q.shape, protos.shape)
-    assert b % bq == 0 and c % bc == 0, (b, bq, c, bc)
     assert 0 < c_real <= c, (c_real, c)
-    grid = (g, b // bq, c // bc)
     kernel = functools.partial(_topk_banked_kernel, c_real, bc)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, w), lambda g, i, j: (g, i, 0)),
-            pl.BlockSpec((1, bc, w), lambda g, i, j: (g, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq), lambda g, i, j: (g, i)),
-            pl.BlockSpec((1, bq), lambda g, i, j: (g, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, b), jnp.int32),
-            jax.ShapeDtypeStruct((g, b), jnp.int32),
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q, protos)
-
-
-# Sorted-key buffer sentinel: strictly greater than every real key (real keys
-# are bounded by (d+1)*C < 2**31, checked by the caller), so padded classes and
-# already-extracted entries can never win a rank. Kept as a Python int —
-# a module-level jnp scalar would be captured as a constant by pallas_call.
-_KEY_SENTINEL = 2**31 - 1
+    return top1_banked_call(kernel, q, protos, bq=bq, bc=bc,
+                            interpret=interpret)
 
 
 def _smallest_k(keys: jax.Array, k: int) -> jax.Array:
@@ -248,7 +273,7 @@ def hamming_topk_k_banked_pallas(
         ],
         out_specs=pl.BlockSpec((1, bq, k), lambda g, i, j: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((g, b, k), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

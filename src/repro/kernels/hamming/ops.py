@@ -244,9 +244,10 @@ def hamming_topk_banked(
     gathers per chunk tile and never materializes the expanded view.
 
     Block sizes default to the `common.hamming_blocks` policy. The top-k
-    kernel needs the int32 key encoding ``dist*C + col`` to fit; if
-    (d+1)*C_padded >= 2^31 the call transparently streams instead (the
-    streamed overflow branch carries (val, idx) pairs).
+    kernel needs the int32 key encoding ``dist*C + col`` to fit. If
+    (d+1)*C_padded >= 2^31, interpret mode streams instead (the streamed
+    overflow branch carries (val, idx) pairs); compiled for the chip, the
+    call raises, so a served top-k never leaves the kernel unannounced.
     """
     if interpret is None:
         interpret = common.default_interpret()
@@ -272,7 +273,14 @@ def hamming_topk_banked(
         return val[:, :b], idx[:, :b]
     assert 1 <= k <= c, (k, c)
     c_pad = common.cdiv(c, bc) * bc
-    if not use_kernel or (w * 32 + 1) * c_pad >= 2**31:
+    overflow = (w * 32 + 1) * c_pad >= 2**31
+    if overflow and use_kernel and not interpret:
+        raise ValueError(
+            f"top-{k} key (d+1)*C = {(w * 32 + 1) * c_pad} overflows int32: "
+            "the fused top-k kernel cannot search this bank; shard the class "
+            "axis over more cores"
+        )
+    if not use_kernel or overflow:
         return _streamed_topk_banked(q, protos, bc, bank_rows=bank_rows, k=k)
     if bank_rows is not None:
         protos = jnp.take(protos, bank_rows, axis=0)        # [G, C, W]

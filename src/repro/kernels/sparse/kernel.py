@@ -17,9 +17,13 @@ Two kernels, mirroring kernels/hamming/kernel.py:
   convention as `hamming_topk_banked_pallas`, so the sparse serve path reuses
   the packed serve's downstream unchanged.
 
-CPU runs use interpret mode (`common.default_interpret()`); the TPU-native
-lowering of the in-kernel gather shares the hamming family's caveat that
-real-TPU validation is still open (ROADMAP).
+TPU mapping: the query tile rides in SMEM, one scalar index per loop step,
+and the prototype tile is transposed once per grid step into a word-major
+[W, bc] VMEM scratch, so the word a query index names is one dynamic
+sublane row holding that word of all bc classes. The gather is then a row
+load per (query, index) — the TPU lowering refuses a vector `jnp.take`
+gather on the class tile. CPU runs use interpret mode
+(`common.default_interpret()`).
 """
 from __future__ import annotations
 
@@ -28,34 +32,53 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.kernels.hamming.kernel import merge_top1, top1_banked_call
 
-# padded class columns get this distance so they never win the running min;
-# a Python int on purpose — a module-level jnp scalar would be captured as a
-# compile-time constant by every kernel body
-_POISON = 2**30
 # sentinel-padded query slots (must match repro.core.sparse.SENTINEL)
 _SENTINEL = 2**31 - 1
 
 
-def _overlap_tile(q: jax.Array, p: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """(dist [bq, bc], valid-count [bq]) from q [bq, k] int32, p [bc, W] u32."""
-    v = q != jnp.int32(_SENTINEL)
-    w = jnp.where(v, q >> 5, 0)
-    bit = jnp.where(v, q & 31, 0).astype(jnp.uint32)
-    sel = jnp.take(p, w, axis=1)  # gather: [bc, bq, k]
-    hit = ((sel >> bit[None]) & jnp.uint32(1)).astype(jnp.int32)
-    ov = jnp.sum(hit * v[None].astype(jnp.int32), axis=-1)  # [bc, bq]
-    cnt = jnp.sum(v, axis=-1).astype(jnp.int32)             # [bq]
-    pop = jnp.sum(jax.lax.population_count(p).astype(jnp.int32), axis=-1)
-    dist = cnt[:, None] + pop[None, :] - 2 * ov.T           # [bq, bc]
-    return dist, cnt
+def _overlap_rows(idx_at, bq: int, k: int, p, pt_ref, store_row):
+    """Distances of bq index-list queries to the bc classes of tile p.
+
+    ``idx_at(i, s)`` reads slot s of query i (a scalar from SMEM); p is the
+    [bc, W] uint32 prototype tile; ``store_row(i, row)`` receives query i's
+    [1, bc] int32 distances ``|q| + |p| - 2 |q AND p|``.
+    """
+    pt_ref[...] = p.T                                         # [W, bc]
+    pop = jnp.sum(jax.lax.population_count(pt_ref[...]).astype(jnp.int32),
+                  axis=0, keepdims=True)                      # [1, bc]
+    bc = pop.shape[1]
+
+    def query(i, carry):
+        def slot(s, acc):
+            ov, cnt = acc
+            idx = idx_at(i, s)
+            valid = idx != _SENTINEL
+            word = pt_ref[pl.ds(jnp.where(valid, idx >> 5, 0), 1), :]
+            bit = jnp.where(valid, idx & 31, 0).astype(jnp.uint32)
+            hit = ((word >> bit) & jnp.uint32(1)).astype(jnp.int32)
+            v = valid.astype(jnp.int32)
+            return ov + hit * v, cnt + v
+
+        ov, cnt = jax.lax.fori_loop(
+            0, k, slot, (jnp.zeros((1, bc), jnp.int32), jnp.int32(0)))
+        store_row(i, cnt + pop - 2 * ov)
+        return carry
+
+    jax.lax.fori_loop(0, bq, query, 0)
 
 
-def _search_kernel(q_ref, p_ref, out_ref):
-    dist, _ = _overlap_tile(q_ref[...], p_ref[...])
-    out_ref[...] = dist
+def _search_kernel(q_ref, p_ref, out_ref, pt_ref):
+    bq, k = q_ref.shape
+
+    def store_row(i, row):
+        out_ref[pl.ds(i, 1), :] = row
+
+    _overlap_rows(lambda i, s: q_ref[i, s], bq, k, p_ref[...], pt_ref,
+                  store_row)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bc", "interpret"))
@@ -67,50 +90,45 @@ def sparse_search_pallas(
     B must be a multiple of bq and C of bc (callers pad; padded query rows are
     all-sentinel, padded class rows all-zero words — both sliced away after).
     """
-    b, _ = q.shape
+    b, k = q.shape
     c, w = protos.shape
     assert b % bq == 0 and c % bc == 0, (q.shape, protos.shape, bq, bc)
     return pl.pallas_call(
         _search_kernel,
         grid=(b // bq, c // bc),
         in_specs=[
-            pl.BlockSpec((bq, q.shape[-1]), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, k), lambda i, j: (i, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((bc, w), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, c), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((w, bc), jnp.uint32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
     )(q, protos)
 
 
-def _topk_banked_kernel(c_real, bc, q_ref, p_ref, val_ref, idx_ref):
+def _topk_banked_kernel(c_real, bc, q_ref, p_ref, val_ref, idx_ref,
+                        pt_ref, dist_ref):
     """Fused per-bank top-1 with a revisited output tile over the class grid.
 
-    Same carry structure as the hamming `_topk_banked_kernel`: grid step j
-    streams class block j through the running (min, argmin); strict `<` in
-    the merge + FIRST-minimum `argmin` inside the block preserve the global
-    first-minimum tie convention of the oracle.
+    Same carry as the hamming top-1 (`merge_top1`): grid step j streams
+    class block j through the running (min, argmin); strict `<` in the merge
+    + FIRST-minimum inside the block preserve the global first-minimum tie
+    convention of the oracle.
     """
-    j = pl.program_id(2)
-    dist, _ = _overlap_tile(q_ref[0], p_ref[0])
-    col = j * bc + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
-    dist = jnp.where(col < c_real, dist, jnp.int32(_POISON))
-    loc_v = jnp.min(dist, axis=-1)
-    loc_i = j * bc + jnp.argmin(dist, axis=-1).astype(jnp.int32)
+    _, bq, k = q_ref.shape
 
-    @pl.when(j == 0)
-    def _init():
-        val_ref[0] = loc_v
-        idx_ref[0] = loc_i
+    def store_row(i, row):
+        dist_ref[pl.ds(i, 1), :] = row
 
-    @pl.when(j > 0)
-    def _update():
-        better = loc_v < val_ref[0]
-        idx_ref[0] = jnp.where(better, loc_i, idx_ref[0])
-        val_ref[0] = jnp.where(better, loc_v, val_ref[0])
+    _overlap_rows(lambda i, s: q_ref[0, i, s], bq, k, p_ref[0], pt_ref,
+                  store_row)
+    merge_top1(c_real, bc, pl.program_id(2), dist_ref[...], val_ref,
+                idx_ref)
 
 
 @functools.partial(
@@ -126,21 +144,11 @@ def sparse_topk_banked_pallas(
     B must be a multiple of bq and C of bc; class columns >= c_real are
     poisoned so padding never wins.
     """
-    g, b, k = q.shape
-    _, c, w = protos.shape
-    assert b % bq == 0 and c % bc == 0, (q.shape, protos.shape, bq, bc)
+    w = protos.shape[-1]
     kernel = functools.partial(_topk_banked_kernel, c_real, bc)
-    return pl.pallas_call(
-        kernel,
-        grid=(g, b // bq, c // bc),
-        in_specs=[
-            pl.BlockSpec((1, bq, k), lambda g, i, j: (g, i, 0)),
-            pl.BlockSpec((1, bc, w), lambda g, i, j: (g, j, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, bq), lambda g, i, j: (g, i))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((g, b), jnp.int32)] * 2,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(q, protos)
+    return top1_banked_call(
+        kernel, q, protos, bq=bq, bc=bc, interpret=interpret,
+        q_memory_space=pltpu.SMEM,
+        scratch_shapes=[pltpu.VMEM((w, bc), jnp.uint32),
+                        pltpu.VMEM((bq, bc), jnp.int32)],
+    )

@@ -1,13 +1,17 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture × input-shape) cell on
 the production meshes and record roofline inputs.
 
-The two lines above run before ANY other import — jax locks the device count on
-first init, and the dry-run needs 512 placeholder CPU devices to build the
-(2, 16, 16) multi-pod mesh. Smoke tests and benchmarks must NOT import this
-module (they see the real single CPU device).
+The lines above run before ANY other import — jax locks the platform and the
+device count on first init, and the dry-run needs 512 placeholder CPU devices
+to build the (2, 16, 16) multi-pod mesh. The dry-run is a CPU tool: pinning
+the platform keeps it (and every child of ``--all``, which inherits the
+environment) off an attached TPU, which one process at a time may hold.
+Smoke tests and benchmarks must NOT import this module (they see the real
+single CPU device).
 
 Per cell this produces benchmarks/artifacts/dryrun/<mesh>/<arch>__<cell>.json:
   * compiled.memory_analysis()  — bytes/device (proves the sharding fits or not);

@@ -120,7 +120,7 @@ def run_hdc_stream(args):
     rep = "unpacked" if args.unpacked else "packed"
     cfg = scaleout.ScaleOutConfig(
         n_classes=args.classes, dim=args.dim, m_tx=3, n_rx_cores=8,
-        batch=args.hdc_batch, use_kernels=False, representation=rep,
+        batch=args.hdc_batch, representation=rep,
         noise="exact",
     )
     mesh = make_mesh((1, 1), ("data", "model"))
@@ -204,6 +204,9 @@ def main():
                     help="(--hdc) elementwise representation instead of packed")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.hdc:
         run_hdc_stream(args)
         return

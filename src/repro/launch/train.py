@@ -48,11 +48,13 @@ def main():
 
     from repro import compat, configs
     from repro.data import DataConfig, SyntheticLM
+    from repro.launch.cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import get_model
     from repro.train.loop import Trainer, TrainerConfig, build_train_fns
     from repro.train.optimizer import OptConfig
 
+    enable_compile_cache()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
     model = get_model(cfg)
     mesh = make_host_mesh()

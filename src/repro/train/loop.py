@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
-from repro.compat import version as compat_version
 from repro.distributed import collectives
 from repro.distributed.sharding import (
     DEFAULT_RULES,
@@ -148,32 +147,17 @@ def build_train_fns(
             "step": (),
         }
     elif opt_cfg.kind == "sign_majority":
-        # Model axes normally stay auto (GSPMD shards the per-device gradient
-        # compute); 0.4.x XLA cannot partition lax.scan inside a partially
-        # manual computation, so there the body goes fully manual and every
-        # model column redundantly computes the same gradients (params and
-        # batch shards are identical along "model" — correct, just unsharded).
-        partial_auto = compat_version.has_partial_auto_shard_map()
-        axes_set = set(dp) if partial_auto else set(mesh.axis_names)
-        # In the fully-manual body no mesh axis is available to GSPMD, so
-        # in-body activation constraints must resolve to replicated.
-        body_rules = rules if partial_auto else {k: None for k in rules}
+        # The dp axes go manual; model axes stay auto (GSPMD shards the
+        # per-device gradient compute). In-body activation constraints may
+        # name only auto axes, so the batch axes resolve to replicated there.
         dp_spec = P(dp if len(dp) > 1 else dp[0])
-        n_dp = 1
-        for a in dp:
-            n_dp *= mesh.axis_sizes[mesh.axis_names.index(a)]
+        body_rules = rules | {"batch": None, "moe_groups": None}
 
-        def per_device(params, batch, key, dp_idx):
-            # dp_idx: [1] shard of the dp-linear iota — this device's index
-            # along the dp axes. Threaded in as a sharded input because
-            # lax.axis_index inside a partially-auto shard_map does not lower
-            # on 0.4.x XLA (see collectives.sign_allreduce).
+        def per_device(params, batch, key):
             with use_rules(body_rules):
                 loss, metrics, grads = accumulate(params, batch)
             votes = jax.tree.map(
-                lambda g: collectives.sign_allreduce(
-                    g, dp, key=key, ber=ota_ber, device_index=dp_idx[0]
-                ),
+                lambda g: collectives.sign_allreduce(g, dp, key=key, ber=ota_ber),
                 grads,
             )
             loss = jax.lax.pmean(loss, dp)
@@ -184,11 +168,11 @@ def build_train_fns(
             votes, loss, metrics = compat.shard_map(
                 per_device,
                 mesh=mesh,
-                in_specs=(P(), batch_specs, P(), dp_spec),
+                in_specs=(P(), batch_specs, P()),
                 out_specs=(P(), P(), P()),
-                axis_names=axes_set,
+                axis_names=set(dp),
                 check_vma=False,
-            )(params, batch, key, jnp.arange(n_dp, dtype=jnp.int32))
+            )(params, batch, key)
             with use_rules(rules):
                 new_params, new_state, om = opt_lib.sign_update(opt_cfg, votes, opt_state, params)
             return new_params, new_state, {"loss": loss, **metrics, **om}

@@ -9,7 +9,7 @@ sys.path.insert(0, os.path.dirname(__file__))  # for `from _propcheck import ...
 
 
 def make_test_mesh(shape, axes):
-    """The one way tests build a mesh — version-portable via repro.compat.
+    """The one way tests build a mesh — through repro.compat (all-auto axes).
 
     Subprocess snippets (tests/test_distributed.py) can't import conftest;
     they use `from repro.compat import make_mesh` directly, which this wraps.

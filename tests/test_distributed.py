@@ -410,6 +410,38 @@ def test_sign_majority_training_converges():
     """)
 
 
+def test_sign_majority_step_on_model_sharded_mesh():
+    """The dp axes are manual inside the sign_majority body: activation
+    constraints there must not name them (a (2, 4) mesh with smollm's
+    batch-constrained layers used to be refused at trace time)."""
+    out = run8("""
+    import math
+    import jax
+    from repro import configs
+    from repro.compat import make_mesh
+    from repro.models import get_model
+    from repro.train.loop import build_train_fns
+    from repro.train.optimizer import OptConfig
+    from repro.data import SyntheticLM, DataConfig
+    mesh = make_mesh((2, 4), ("data", "model"))
+    cfg = configs.get_smoke("smollm_360m")
+    model = get_model(cfg)
+    pipe = SyntheticLM(DataConfig(vocab=cfg.vocab, seq=64, global_batch=8))
+    key = jax.random.PRNGKey(0)
+    opt = OptConfig(kind="sign_majority", lr=1e-3, warmup=2, total_steps=4)
+    fns = build_train_fns(model, mesh, opt, ota_ber=0.01)
+    params, opt_state = fns.init(key)
+    params = jax.device_put(params, fns.param_shardings)
+    opt_state = jax.device_put(opt_state, fns.opt_shardings)
+    for step in range(2):
+        params, opt_state, m = fns.step(params, opt_state, pipe.batch(step),
+                                        jax.random.fold_in(key, step))
+    assert math.isfinite(float(m["loss"]))
+    print("OK")
+    """)
+    assert "OK" in out
+
+
 def test_dense_dp_equals_single_device():
     """Same seeds: 8-device DP adamw training == 1-device training."""
     code_tpl = """

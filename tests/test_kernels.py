@@ -112,6 +112,16 @@ def test_hamming_topk_streamed_both_branches(key_encode):
     assert int(i[0, 0]) == 130  # the first duplicate wins
 
 
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True)])
+def test_interpret_mode_only_off_the_chip(monkeypatch, backend, interpret):
+    """Kernels compile for the chip on a TPU backend; interpret mode is what
+    the CPU runs."""
+    from repro.kernels import common
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert common.default_interpret() is interpret
+
+
 def test_hamming_banked_equals_per_bank_loop():
     """One banked launch == G independent hamming_search calls."""
     g, b, c, d = 5, 6, 40, 512

@@ -1,6 +1,12 @@
 """End-to-end paper pipeline: EM channel -> joint phase search -> BER -> HDC
 accuracy, plus the distributed serve path wired to the pre-characterized BER —
-the full Fig. 5 methodology in one test module."""
+the full Fig. 5 methodology in one test module, and the bring-up entry points
+around it."""
+import os
+import shutil
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -119,3 +125,92 @@ def test_permuted_bundling_identifies_transmitter(pipeline):
     cfg = classifier.HDCTaskConfig(n_classes=100, dim=512, n_trials=300)
     acc = float(classifier.run_accuracy(KEY, cfg, 5, float(res.avg_ber), "permuted"))
     assert acc >= 0.99
+
+
+# ---------------------------------------------------------------------------
+# bring-up entry points: chip_smoke.py, the compile cache, the CPU dry-run
+# ---------------------------------------------------------------------------
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+
+
+def _run(args, tmp_path, *, devices=1, cwd=REPO, timeout=600, **env_extra):
+    """Run a fresh interpreter on the CPU with its own compile cache."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env.update({"JAX_PLATFORMS": "cpu",
+                "PYTHONPATH": os.path.join(REPO, "src"),
+                "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
+                **env_extra})
+    if devices > 1:
+        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    return subprocess.run([sys.executable, *args], capture_output=True,
+                          text=True, timeout=timeout, env=env, cwd=cwd)
+
+
+@pytest.mark.parametrize("flags,devices", [
+    ((), 1),
+    (("--tiny",), 1),
+    (("--tiny", "--chips", "4"), 4),
+], ids=["default", "tiny", "tiny-4chips"])
+def test_chip_smoke_refuses_ok_without_tpu(tmp_path, flags, devices):
+    """Without a TPU the script exits non-zero and prints no result. With
+    --tiny it first runs every phase at a toy size (kernels in interpret
+    mode): the ideal serves match serve_reference on every trial."""
+    r = _run([os.path.join(REPO, "chip_smoke.py"), *flags], tmp_path,
+             devices=devices)
+    assert r.returncode != 0, r.stdout
+    assert '"ok"' not in r.stdout
+    if flags:
+        refs = [ln for ln in r.stdout.splitlines() if "reference[" in ln]
+        assert len(refs) == (3 if devices == 4 else 1), r.stdout + r.stderr
+        assert all(" 0 of 16 predictions differ" in ln for ln in refs), r.stdout
+        assert "rehearsal passed, but no TPU" in r.stderr
+    else:
+        assert "no TPU found" in r.stderr
+
+
+def test_chip_smoke_alone_fails(tmp_path):
+    """Copied out of the repository, the script cannot run and says nothing."""
+    alone = tmp_path / "alone"
+    alone.mkdir()
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), alone)
+    r = _run(["chip_smoke.py", "--tiny"], tmp_path, cwd=alone,
+             PYTHONPATH="")
+    assert r.returncode != 0
+    assert '"ok"' not in r.stdout
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the one cache directory and
+    compiled programs land there; unset, the cache is <repo>/.jax_cache."""
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.launch.cache import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+    )
+    if from_env:
+        code += "jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()\n"
+        r = _run(["-c", code], tmp_path,
+                 JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                 JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+        want = str(tmp_path / "cache")
+    else:
+        r = _run(["-c", code], tmp_path, JAX_COMPILATION_CACHE_DIR="")
+        want = os.path.join(REPO, ".jax_cache")
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.split() == [want, want]
+    if from_env:
+        assert os.listdir(want), "no compiled program was cached"
+
+
+def test_dryrun_pins_cpu(tmp_path):
+    """The dry-run is a CPU tool: importing it pins the CPU platform and its
+    512 placeholder devices before JAX starts, whatever the environment."""
+    code = ("import repro.launch.dryrun, jax\n"
+            "print(jax.devices()[0].platform, len(jax.devices()))\n")
+    r = _run(["-c", code], tmp_path, JAX_PLATFORMS="tpu")
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.split() == ["cpu", "512"]

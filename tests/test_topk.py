@@ -147,6 +147,24 @@ def test_topk_bank_rows_indirection(use_kernel):
         np.testing.assert_array_equal(np.asarray(gx), np.asarray(rx))
 
 
+@pytest.mark.parametrize("interpret", [True, False])
+def test_topk_key_overflow_streams_only_in_interpret_mode(interpret):
+    """(d+1)*C >= 2**31 cannot be key-encoded: interpret mode streams the
+    (val, idx) carry instead, the chip's kernel path refuses the shape."""
+    q = jax.ShapeDtypeStruct((1, 8, 2048), jnp.uint32)        # d = 65,536
+    p = jax.ShapeDtypeStruct((1, 32768, 2048), jnp.uint32)    # C = 32,768
+
+    def call(q, p):
+        return hamming_topk_banked(q, p, k=2, interpret=interpret)
+
+    if interpret:
+        dist, idx = jax.eval_shape(call, q, p)
+        assert dist.shape == idx.shape == (1, 8, 2)
+    else:
+        with pytest.raises(ValueError, match="overflows int32"):
+            jax.eval_shape(call, q, p)
+
+
 def test_hamming_blocks_policy():
     # defaults below / at the tall-C threshold; explicit overrides always win
     assert common.hamming_blocks(64, 512) == (common.BQ, common.BC)
