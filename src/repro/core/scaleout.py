@@ -286,6 +286,10 @@ def _local_search(q: jax.Array, protos: jax.Array, use_kernels: bool) -> jax.Arr
 # (axis=-2 encoder sums, shape[:-1] reshapes) so the multi-tenant serve can
 # flatten its [N_slots, B] rows through the same collectives — elementwise
 # over rows, hence bit-identical per row to a standalone serve of that row.
+# The OTA bundle, the RX fan-out, the shard search and the global top-1 each
+# run under a `jax.named_scope` (ota_bundle, rx_copies, search, top1_gather):
+# the compiled step's ops carry it in their op_name metadata, so device time
+# is attributed to the stage it belongs to; values are unchanged.
 # ---------------------------------------------------------------------------
 
 def _tx_ids(cfg: ScaleOutConfig, e_per: int):
@@ -311,6 +315,7 @@ def _dpos(mesh: Mesh, dp: tuple[str, ...]):
     )
 
 
+@jax.named_scope("ota_bundle")
 def _ota_bundle(cfg: ScaleOutConfig, chan, model_size: int, e_per: int,
                 q_mine, gids, n_act_local, fstate=None):
     """The OTA collective over the encoder/model axis.
@@ -414,6 +419,7 @@ def _ota_bundle(cfg: ScaleOutConfig, chan, model_size: int, e_per: int,
     raise ValueError(cfg.collective)
 
 
+@jax.named_scope("rx_copies")
 def _rx_fanout(cfg: ScaleOutConfig, chan, cores_per_shard: int, tx,
                q_bundled, state, kq):
     """Per-core decode through the PHY tier: each of this shard's IMC cores
@@ -612,6 +618,7 @@ def _coarse_fine_unpacked(cfg: ScaleOutConfig, banks, q, bank_rows=None):
     return val, row.astype(jnp.int32)
 
 
+@jax.named_scope("search")
 def _shard_top1(cfg: ScaleOutConfig, cores_per_shard: int, tx, q_rx, protos,
                 qmask=None, stuck=None):
     """This shard's local top-1: each core searches its class sub-shard (with
@@ -727,6 +734,7 @@ def _shard_top1(cfg: ScaleOutConfig, cores_per_shard: int, tx, q_rx, protos,
     return val, idx
 
 
+@jax.named_scope("top1_gather")
 def _gather_top1(cfg: ScaleOutConfig, val, idx):
     """Global top-1: tiny (value, index) all-gather over the cores."""
     vals = jax.lax.all_gather(val, "model")           # [S_tx, ...]
@@ -1001,6 +1009,7 @@ def make_ota_serve(
     return jax.jit(fn)
 
 
+@jax.named_scope("search")
 def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
                       q_rx, store, rows, qmask=None, stuck=None):
     """Slot-batched local top-1: slot s searches tenant bank ``rows[s]`` of the

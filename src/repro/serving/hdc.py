@@ -38,6 +38,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import faults, phy
@@ -252,17 +253,19 @@ class HDCEngine(slotring.SlotRingEngine):
         K — at most ``num_slots`` programs for the engine's lifetime). A
         per-request ``_admit_fn`` dispatch costs about half a standalone
         serve, so filling 8 slots one-by-one would erase the step's batching
-        win; scattering them at once keeps admission at ~1 dispatch/step."""
-        rows = [self._tenant_row(t) for t in tenant_ids]
+        win; scattering them at once keeps admission at ~1 dispatch/step.
+        The scatter's dispatch is the host span ``hdc.admit_scatter``; the
+        rest of the call is the host's bookkeeping."""
+        rows = np.asarray([self._tenant_row(t) for t in tenant_ids], np.int32)
         for q in queries:
             if tuple(q.shape) != self._qshape:
                 raise ValueError(
                     f"queries must be {self._qshape}, got {tuple(q.shape)}"
                 )
-        return self._admit_many_fn(
-            state, tuple(queries), np.asarray(rows, np.int32),
-            tuple(keys), np.asarray(slots, np.int32),
-        )
+        slots = np.asarray(slots, np.int32)
+        with TraceAnnotation("hdc.admit_scatter"):
+            return self._admit_many_fn(state, tuple(queries), rows,
+                                       tuple(keys), slots)
 
     def step(self, params, state):
         store, chan_state = params
@@ -691,11 +694,16 @@ class HDCScheduler(SlotScheduler):
         return []
 
     def _collect(self, emitted) -> list:
+        """The step barrier: wait for the step's results and copy them to the
+        host (span ``hdc.fetch``), run the engine's barrier hook (span
+        ``hdc.barrier``), then build a completion for every running slot."""
         pred, maxsim = emitted
-        p = np.asarray(pred)        # device sync: this is the step barrier
-        s = np.asarray(maxsim)
-        self.engine.on_barrier()    # adaptive engines: commit the evolved
-        #   process state + run the link controller on settled values
+        with TraceAnnotation("hdc.fetch"):
+            p = np.asarray(pred)
+            s = np.asarray(maxsim)
+        with TraceAnnotation("hdc.barrier"):
+            self.engine.on_barrier()    # adaptive engines: commit the evolved
+            #   process state + run the link controller on settled values
         finished = []
         for slot in sorted(self.running):
             req, t_admit = self.running.pop(slot)

@@ -19,6 +19,14 @@ prompt that arrived in between (the bucket-drain policy kept picking the long
 bucket because its head stayed oldest while the drained entries were
 refilled behind it).
 
+Each ``step`` is one host span ``scheduler.step`` (the step's index and the
+slots it runs) enclosing ``scheduler.admit``, ``scheduler.dispatch`` and
+``scheduler.collect``: ``jax.profiler.TraceAnnotation``s, which a profiler
+trace records on the clock of the device's ops and which do nothing else
+while no trace is recording. ``slot_steps`` counts the running slots of
+every engine step and ``computed_slot_steps`` the slots those steps computed,
+so their ratio is the share of the device's slot work that served a request.
+
 Eviction is step-granular: a finished slot is freed immediately and refilled
 on the next admission pass while the remaining slots keep going — no drain
 barrier, no recompile.
@@ -42,6 +50,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.engine import ChunkedPrefill, ContinuousEngine, _prompt_sig
 
@@ -111,6 +120,8 @@ class SlotScheduler:
         )
         self.results: dict[int, Completion] = {}
         self.steps = 0
+        self.slot_steps = 0            # running slots, summed over engine steps
+        self.computed_slot_steps = 0   # slots computed, summed over engine steps
         self._next_rid = 0
         self.max_slot_steps = max_slot_steps
         self.max_requeues = max_requeues
@@ -212,17 +223,25 @@ class SlotScheduler:
         """Advance in-flight admissions one unit, fill free slots, run one
         multi-slot engine step, collect finished slots. Returns the requests
         completed during this call."""
-        finished = self._advance_admissions()
-        finished.extend(self._admit_free_slots())
-        if not self.running:
+        with TraceAnnotation("scheduler.step", step=self.steps) as span:
+            with TraceAnnotation("scheduler.admit"):
+                finished = self._advance_admissions()
+                finished.extend(self._admit_free_slots())
+            if not self.running:
+                return finished
+            stepped = list(self.running)
+            span.set_metadata(slots=len(stepped))
+            with TraceAnnotation("scheduler.dispatch"):
+                self.state, emitted = self.engine.step(self._step_params(),
+                                                       self.state)
+            self.steps += 1
+            self.slot_steps += len(stepped)
+            self.computed_slot_steps += self.engine.num_slots
+            with TraceAnnotation("scheduler.collect"):
+                finished.extend(self._collect(emitted))
+            if self.max_slot_steps is not None:
+                finished.extend(self._enforce_deadlines(stepped))
             return finished
-        stepped = list(self.running)
-        self.state, emitted = self.engine.step(self._step_params(), self.state)
-        self.steps += 1
-        finished.extend(self._collect(emitted))
-        if self.max_slot_steps is not None:
-            finished.extend(self._enforce_deadlines(stepped))
-        return finished
 
     def run(self, timeout: float | None = None) -> dict[int, Completion]:
         """Step until the queue and all slots drain. Returns {rid: Completion}."""

@@ -278,3 +278,31 @@ def test_slot_leak_guard_evicts_requeues_and_drains():
     assert sched.steps == 6                  # 3 per attempt, 2 attempts
     assert len(eng.evicted) == 4             # 2 slots x 2 attempts
     assert not sched.running and sorted(sched.free) == [0, 1]
+
+
+def test_scheduler_counts_running_and_computed_slot_steps():
+    """``slot_steps`` sums the running slots of every engine step and
+    ``computed_slot_steps`` the slots each step computed, so a drain with
+    uneven request lengths reads a slot fill below 1."""
+    cfg = configs.get_smoke("smollm_360m")
+    model = get_model(cfg)
+    params = init_params(jax.random.PRNGKey(1), model.specs)
+    eng = ContinuousEngine(model, ServeConfig(max_new=4, temperature=0.0),
+                           num_slots=2, max_prompt_len=8)
+    sched = Scheduler(eng, params)
+    running = []
+    step = eng.step
+
+    def counted(p, state):
+        running.append(len(sched.running))
+        return step(p, state)
+
+    eng.step = counted
+    for i, new in enumerate((4, 2, 2)):
+        sched.submit(jax.random.randint(jax.random.PRNGKey(30 + i), (8,), 0,
+                                        cfg.vocab), max_new=new)
+    sched.run(timeout=600)
+    assert sched.steps == len(running) and min(running) == 1
+    assert sched.slot_steps == sum(running)
+    assert sched.computed_slot_steps == eng.num_slots * sched.steps
+    assert sched.slot_steps < sched.computed_slot_steps

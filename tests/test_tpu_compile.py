@@ -12,6 +12,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and test collection must
 not depend on which worker got it.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -165,8 +167,9 @@ def test_standalone_serve_compiles_for_v5e(topo, monkeypatch, chips,
 
 def test_ring_step_fits_one_v5e(topo, monkeypatch):
     """The multi-tenant ring step of chip_smoke.py (2 slots x 512 trials,
-    8 tenants resident) compiles for one chip: its search is a Mosaic kernel
-    and its HBM fits 16 GB (the compiler refuses a program that does not)."""
+    8 tenants resident) compiles for one chip: its search is a Mosaic kernel,
+    its HBM fits 16 GB (the compiler refuses a program that does not), and
+    its ops keep the serve stages' named scopes in their metadata."""
     from repro.core import scaleout
     from repro.kernels import common
 
@@ -184,3 +187,6 @@ def test_ring_step_fits_one_v5e(topo, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    text = compiled.as_text()
+    for scope in ("ota_bundle", "rx_copies", "search", "top1_gather"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
