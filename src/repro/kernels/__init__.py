@@ -5,8 +5,9 @@ Each subpackage has kernel.py (pl.pallas_call + BlockSpec VMEM tiling), ops.py
 used by the allclose test sweeps).
 
 * hamming/      packed XOR+popcount similarity search (memory-bound IMC path),
-  incl. the fused top-1 `hamming_topk_banked` (class axis reduced in VMEM —
-  the [G, B, C] distance tensor never reaches HBM; EXPERIMENTS.md §Perf)
+  incl. the fused top-1 `hamming_topk_banked` (a bipolar matmul on the MXU
+  over bit planes unpacked in VMEM, class axis reduced in VMEM — the
+  [G, B, C] distance tensor never reaches HBM; EXPERIMENTS.md §Perf)
 * majority/     bit-wise majority bundling (the op the paper computes over-the-air)
 * assoc_matmul/ bipolar MXU matmul (compute-bound IMC crossbar MVM analogue)
 * flash_attention/ fused causal attention fwd (the fix for the dominant

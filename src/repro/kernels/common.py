@@ -6,6 +6,8 @@ same BlockSpec pipeline, so index maps / tiling bugs surface on CPU.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 
 
@@ -61,3 +63,40 @@ def hamming_blocks(
     if bc is None:
         bc = 4 * BC if c >= TALL_C else BC
     return bq, bc
+
+
+# The fused top-1 runs on the MXU, so its tiles follow the matmul and not the
+# VPU: a query block holds the bank's whole trial axis up to ``TOP1_BQ`` rows,
+# a class block the bank's whole class axis below ``TALL_C``, and a grid step
+# holds whole banks until it has about ``TOP1_STEP_MACS`` bit products.
+TOP1_BQ = 512
+TOP1_STEP_MACS = 2**27
+
+
+def top1_blocks(
+    g: int, b: int, c: int, w: int, bq: int | None = None,
+    bc: int | None = None,
+) -> tuple[int, int, int]:
+    """Resolve (banks per step, bq, bc) for the fused top-1 over ``g`` banks
+    of ``b`` queries and ``c`` classes of ``w`` packed words; explicit values
+    win, ``None`` takes the policy.
+
+    - ``bq``: ``b`` rounded up to 8 while that is at most ``TOP1_BQ``, else
+      128, 256 or 512 rows, whichever divides ``b`` rounded up to 128 (an
+      output block's last dim is the whole padded axis or lane-aligned).
+    - ``bc``: all ``c`` classes in one block (a full-dim block needs no
+      padding, as at 100 classes a core), or ``4 * BC`` on a tall class axis,
+      where the kernel carries the running (min, argmin) across class blocks.
+    - banks per step: the largest divisor of ``g`` that keeps a step near
+      ``TOP1_STEP_MACS``, so small banks (64 trials of 512 bits) do not each
+      pay a grid step's overhead.
+    """
+    if bq is None:
+        bq = cdiv(b, 8) * 8
+        if bq > TOP1_BQ:
+            bq = 128 * math.gcd(cdiv(b, 128), TOP1_BQ // 128)
+    if bc is None:
+        bc = 4 * BC if c >= TALL_C else c
+    want = max(1, TOP1_STEP_MACS // (bq * bc * 32 * w))
+    nb = max(k for k in range(1, min(g, want) + 1) if g % k == 0)
+    return nb, bq, bc

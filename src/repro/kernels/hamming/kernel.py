@@ -1,18 +1,35 @@
-"""Pallas TPU kernel: batched packed Hamming distance (XOR + popcount).
+"""Pallas TPU kernels: batched packed Hamming distance.
 
 The associative-memory similarity search of the paper (Fig. 2) over bit-packed
-hypervectors. One output tile [bq, bc] is produced per grid step from a query tile
-[bq, W] and a prototype tile [bc, W] resident in VMEM; the packed dimension W is
-small (d/32 words; 16 words for d=512, 313 for d=10,000) so it is not tiled.
+hypervectors. The packed dimension W is small (d/32 words; 16 words for d=512,
+313 for d=10,000) so it is not tiled.
 
 TPU mapping notes:
-* uint32 bitwise XOR + population_count lower to the VPU; the [bq, bc, W] intermediate
-  stays in VREGs/VMEM (bq=8, bc=128, W<=512 -> <=2 MiB).
-* last-dim block sizes are multiples of 128 lanes; bq rides the 8-sublane dimension.
-* the fused top-1 carries its running (min, argmin) in [G, B, 1] outputs with
-  (1, bq, 1) blocks: the TPU lowering refuses an output block whose last two
-  dims are neither (8, 128)-aligned nor the array's own dims, which a (1, bq)
-  block on a [G, B] array is.
+* the distance tables and the fused top-k: uint32 bitwise XOR +
+  population_count on the VPU; one [bq, bc] tile per grid step from a
+  [bq, bc, W] intermediate in VREGs/VMEM (bq=8, bc=128, W<=512 -> <=2 MiB).
+  Last-dim block sizes are multiples of 128 lanes; bq rides the 8 sublanes.
+* the fused top-1 (`hamming_topk_banked_pallas`): a bipolar dot product on
+  the MXU. With bit b mapped to (-1)^b, dot(q, p) = d - 2 * hamming(q, p),
+  exact from int8 +-1 operands into int32. Each bank's [rows, W] words are
+  transposed once to [W, rows]; bits s, s + 8, s + 16, s + 24 are then one
+  shift, mask, multiply and OR per word, bitcast to [4W, rows] int8, so the
+  contraction (K) axis runs along sublanes in the order (plane, word, byte)
+  on both sides, a fixed permutation of d. Nothing unpacked leaves VMEM.
+  Planes are grouped into K chunks of about ``_TOP1_K`` rows; the
+  [bc, bq] dot tile is reduced over its class (sublane) axis to a
+  lane-dense [1, bq] (max dot, first argmax) row. (bf16 +-1 operands,
+  two per word, are exact too and ran 20% slower on a TPU v5e at
+  WHYPE's banks.)
+  Blocks: a bank's whole trial axis and whole class axis (100 classes is
+  the array's full dim, so no padding), several banks a grid step where
+  banks are small (`common.top1_blocks`); a tall class axis keeps a class
+  grid axis with the running (min, argmin) carried in the output block.
+* the sparse top-1 shares `merge_top1` / `top1_banked_call`, which carry
+  (min, argmin) in [G, B, 1] outputs with (1, bq, 1) blocks: the TPU
+  lowering refuses an output block whose last two dims are neither
+  (8, 128)-aligned nor the array's own dims, which a (1, bq) block on a
+  [G, B] array is.
 """
 from __future__ import annotations
 
@@ -84,24 +101,6 @@ def hamming_banked_pallas(
     )(q, protos)
 
 
-def _topk_banked_kernel(c_real: int, bc: int, q_ref, p_ref, val_ref, idx_ref):
-    """Fused top-1 step: revisits the (g, i) output tile across the j grid axis.
-
-    The running (min_dist, argmin) pair lives in the output VMEM tiles — the
-    [bq, bc] distance tile is reduced in-register and never reaches HBM (the
-    IMC macro's in-memory argmax, Karunaratne et al. 2020). Ties break toward
-    the lowest class index: argmin is first-match inside a tile and the strict
-    `<` merge keeps the earlier tile, matching `jnp.argmax` on similarities
-    (= first minimum of distances) exactly.
-    """
-    j = pl.program_id(2)
-    q = q_ref[0]  # [bq, W] uint32 — this bank's query tile
-    p = p_ref[0]  # [bc, W] uint32 — this bank's prototype tile
-    x = jnp.bitwise_xor(q[:, None, :], p[None, :, :])        # [bq, bc, W]
-    dist = jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=-1)
-    merge_top1(c_real, bc, j, dist, val_ref, idx_ref)
-
-
 def merge_top1(c_real: int, bc: int, j, dist, val_ref, idx_ref):
     """Fold one [bq, bc] distance tile (class block j) into the running
     (min, first argmin) carry held in the [1, bq, 1] output tiles. Classes at
@@ -157,33 +156,133 @@ def top1_banked_call(kernel, q, protos, *, bq: int, bc: int, interpret: bool,
     return val[..., 0], idx[..., 0]
 
 
-@functools.partial(jax.jit, static_argnames=("c_real", "bq", "bc", "interpret"))
+# Bits per packed word, bits per MXU operand, and rows of the contraction
+# axis per MXU chunk.
+_WORD_BITS = 32
+_OPERAND_BITS = 8
+_TOP1_K = 256
+
+
+def _bipolar_planes(words_t: jax.Array, s: int) -> jax.Array:
+    """[W, X] uint32 words -> [4W, X] int8 in {+1, -1}: bits s, s + 8,
+    s + 16 and s + 24 of every word as (-1)**bit, the four bytes of one
+    uint32 each (a byte of 1 times 0xFE, OR 1, is 0xFF = -1)."""
+    y = jax.lax.shift_right_logical(words_t, jnp.uint32(s)) & jnp.uint32(0x01010101)
+    y = (y * jnp.uint32(0xFE)) | jnp.uint32(0x01010101)
+    return pltpu.bitcast(y, jnp.int8)
+
+
+def _bipolar_dot(q_words: jax.Array, p_words: jax.Array) -> jax.Array:
+    """[bq, W] and [bc, W] packed words -> [bc, bq] int32 bipolar dots on the
+    MXU, a power-of-two number of plane sets (4W rows each) a chunk, about
+    ``_TOP1_K`` rows."""
+    q_t, p_t = q_words.T, p_words.T                   # [W, bq], [W, bc]
+    rows = _WORD_BITS // _OPERAND_BITS * q_t.shape[0]
+    group = min(_OPERAND_BITS, 1 << max(0, (_TOP1_K // rows).bit_length() - 1))
+    dot = None
+    for s0 in range(0, _OPERAND_BITS, group):
+        planes = range(s0, s0 + group)
+        qs = jnp.concatenate([_bipolar_planes(q_t, s) for s in planes], 0)
+        ps = jnp.concatenate([_bipolar_planes(p_t, s) for s in planes], 0)
+        part = jax.lax.dot_general(                   # contract K of both
+            ps, qs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        )
+        dot = part if dot is None else dot + part
+    return dot
+
+
+def _topk_banked_mxu_kernel(c_real: int, bc: int, nb: int, n_j: int,
+                            q_ref, p_ref, val_ref, idx_ref):
+    """Fused top-1 of ``nb`` banks against class block j: bipolar dots on
+    the MXU, reduced over the class axis in VMEM.
+
+    The max dot is the min distance; ties break toward the lowest class
+    index (first maximum inside a block, and a strict `<` merge across class
+    blocks keeps the earlier block), matching `jnp.argmin` over distances.
+    Classes at or beyond ``c_real`` are padding and never win.
+    """
+    j = pl.program_id(2)
+    d = _WORD_BITS * q_ref.shape[-1]
+
+    def bank(i):
+        dot = _bipolar_dot(q_ref[i], p_ref[i])               # [bc, bq]
+        col = j * bc + jax.lax.broadcasted_iota(jnp.int32, dot.shape, 0)
+        if c_real < n_j * bc:
+            dot = jnp.where(col < c_real, dot, jnp.int32(-_KEY_SENTINEL))
+        best = jnp.max(dot, axis=0, keepdims=True)           # [1, bq]
+        arg = jnp.min(jnp.where(dot == best, col, jnp.int32(_KEY_SENTINEL)),
+                      axis=0, keepdims=True)
+        dist = (d - best) // 2
+        if n_j == 1:
+            val_ref[i], idx_ref[i] = dist, arg
+            return
+
+        @pl.when(j == 0)
+        def _init():
+            val_ref[i], idx_ref[i] = dist, arg
+
+        @pl.when(j > 0)
+        def _update():
+            better = dist < val_ref[i]
+            idx_ref[i] = jnp.where(better, arg, idx_ref[i])
+            val_ref[i] = jnp.where(better, dist, val_ref[i])
+
+    def body(i, carry):
+        bank(i)
+        return carry
+
+    jax.lax.fori_loop(0, nb, body, 0)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("c_real", "nb", "bq", "bc", "interpret")
+)
 def hamming_topk_banked_pallas(
     q: jax.Array,
     protos: jax.Array,
     *,
     c_real: int,
-    bq: int = 8,
-    bc: int = 128,
+    nb: int,
+    bq: int,
+    bc: int,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-bank fused top-1 Hamming search in ONE kernel launch.
 
     q [G, B, W] uint32, protos [G, C, W] uint32 -> (min_dist, argmin), each
-    [G, B] int32, over bank g's own prototypes. Same grid (G, B/bq, C/bc) as
-    `hamming_banked_pallas`, but the class axis is reduced inside the kernel:
-    the output tile (indexed by (g, i) only) stays resident in VMEM across the
-    j steps and carries the running (min, argmin), so the [G, B, C] distance
-    tensor never exists in HBM. `c_real` (<= C) masks zero-padded prototype
-    rows. B % bq == C % bc == 0.
+    [G, B] int32, over bank g's own prototypes. Grid (G/nb, B/bq, C/bc):
+    ``nb`` banks a step, each a bipolar matmul on the MXU whose [bc, bq]
+    dots are reduced over the class axis in VMEM; across class blocks the
+    output block (indexed by (g, i) only) carries the running (min, argmin),
+    so neither the unpacked bits nor the [G, B, C] distances reach HBM.
+    `c_real` (<= C) masks zero-padded prototype rows. G % nb == B % bq ==
+    C % bc == 0.
     """
     g, b, w = q.shape
     g2, c, w2 = protos.shape
     assert g == g2 and w == w2, (q.shape, protos.shape)
+    assert g % nb == 0 and b % bq == 0 and c % bc == 0, (
+        q.shape, protos.shape, nb, bq, bc
+    )
     assert 0 < c_real <= c, (c_real, c)
-    kernel = functools.partial(_topk_banked_kernel, c_real, bc)
-    return top1_banked_call(kernel, q, protos, bq=bq, bc=bc,
-                            interpret=interpret)
+    kernel = functools.partial(_topk_banked_mxu_kernel, c_real, bc, nb, c // bc)
+    out = jax.ShapeDtypeStruct((g, 1, b), jnp.int32)
+    out_spec = pl.BlockSpec((nb, 1, bq), lambda g, i, j: (g, 0, i))
+    val, idx = pl.pallas_call(
+        kernel,
+        grid=(g // nb, b // bq, c // bc),
+        in_specs=[
+            pl.BlockSpec((nb, bq, w), lambda g, i, j: (g, i, 0)),
+            pl.BlockSpec((nb, bc, w), lambda g, i, j: (g, j, 0)),
+        ],
+        out_specs=[out_spec, out_spec],
+        out_shape=[out, out],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+    )(q, protos)
+    return val[:, 0], idx[:, 0]
 
 
 def _smallest_k(keys: jax.Array, k: int) -> jax.Array:

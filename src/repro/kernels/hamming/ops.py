@@ -229,7 +229,8 @@ def hamming_topk_banked(
 
     Bank g's queries are searched only against bank g's prototypes and the
     class axis is reduced without writing the [G, B, C] distances to HBM —
-    the kernel carries the running (min, argmin) in the revisited output VMEM
+    the top-1 kernel takes bipolar dots on the MXU and reduces them in VMEM,
+    the top-k kernel carries a sorted key buffer in the revisited output
     tile; the jnp fallback streams prototype chunks through the same carry.
     Ties break toward the lowest class index (first minimum), exactly
     `jnp.argmax` over sims = d - 2*dist. B is zero-padded to bq and sliced
@@ -243,7 +244,8 @@ def hamming_topk_banked(
     (same footprint the direct [G, C, W] call pays); the streamed fallback
     gathers per chunk tile and never materializes the expanded view.
 
-    Block sizes default to the `common.hamming_blocks` policy. The top-k
+    Block sizes default to the `common.top1_blocks` policy for the top-1
+    kernel and to `common.hamming_blocks` otherwise. The top-k
     kernel needs the int32 key encoding ``dist*C + col`` to fit. If
     (d+1)*C_padded >= 2^31, interpret mode streams instead (the streamed
     overflow branch carries (val, idx) pairs); compiled for the chip, the
@@ -259,18 +261,20 @@ def hamming_topk_banked(
         assert bank_rows.shape == (g,) and w == w2, (
             q.shape, protos.shape, bank_rows.shape
         )
-    bq, bc = common.hamming_blocks(b, c, bq, bc)
     if k is None:
         if not use_kernel:
+            _, bc = common.hamming_blocks(b, c, bq, bc)
             return _streamed_topk_banked(q, protos, bc, bank_rows=bank_rows)
         if bank_rows is not None:
             protos = jnp.take(protos, bank_rows, axis=0)    # [G, C, W]
+        nb, bq, bc = common.top1_blocks(g, b, c, w, bq, bc)
         qp = common.pad_dim(q, 1, bq)
         pp = common.pad_dim(protos, 1, bc)
         val, idx = hamming_topk_banked_pallas(
-            qp, pp, c_real=c, bq=bq, bc=bc, interpret=interpret
+            qp, pp, c_real=c, nb=nb, bq=bq, bc=bc, interpret=interpret
         )
         return val[:, :b], idx[:, :b]
+    bq, bc = common.hamming_blocks(b, c, bq, bc)
     assert 1 <= k <= c, (k, c)
     c_pad = common.cdiv(c, bc) * bc
     overflow = (w * 32 + 1) * c_pad >= 2**31

@@ -12,10 +12,10 @@ Two kernels, mirroring kernels/hamming/kernel.py:
 
 * `sparse_search_pallas` — full distance tile [bq, bc] per grid step (the
   classifier's top-m decision needs every class's distance);
-* `sparse_topk_banked_pallas` — fused per-bank top-1 with the same
-  revisited-output-tile running (min, argmin) carry and FIRST-minimum tie
-  convention as `hamming_topk_banked_pallas`, so the sparse serve path reuses
-  the packed serve's downstream unchanged.
+* `sparse_topk_banked_pallas` — fused per-bank top-1 with a
+  revisited-output-tile running (min, argmin) carry (`merge_top1`) and the
+  FIRST-minimum tie convention of `hamming_topk_banked_pallas`, so the sparse
+  serve path reuses the packed serve's downstream unchanged.
 
 TPU mapping: the query tile rides in SMEM, one scalar index per loop step,
 and the prototype tile is transposed once per grid step into a word-major

@@ -53,7 +53,17 @@ def test_hamming_banked_kernel_sweep(g, b, c, d):
     )
 
 
-@pytest.mark.parametrize("g,b,c,d", BANKED_SHAPES + [(2, 3, 300, 512)])
+# the served banks' geometry: 100 classes a core (a full-dim class block,
+# not a multiple of 8), W = 16 (Table I, B = 64) and W = 64 (WHYPE, B = 512),
+# a B that is not a multiple of 8, a B above one 512-row query block, and a
+# class axis above TALL_C (class blocks of 512 with the running carry)
+SERVED_BANKS = [(3, 64, 100, 512), (2, 512, 100, 2048), (2, 13, 100, 2048),
+                (2, 600, 100, 512), (1, 5, 4100, 256)]
+
+
+@pytest.mark.parametrize(
+    "g,b,c,d", BANKED_SHAPES + [(2, 3, 300, 512)] + SERVED_BANKS
+)
 @pytest.mark.parametrize("use_kernel", [True, False])
 def test_hamming_topk_banked_sweep(g, b, c, d, use_kernel):
     """Fused top-1 (kernel and streaming-jnp fallback) == jnp min/argmin oracle."""
@@ -69,20 +79,25 @@ def test_hamming_topk_banked_sweep(g, b, c, d, use_kernel):
 @pytest.mark.parametrize("use_kernel", [True, False])
 def test_hamming_topk_banked_tie_breaking(use_kernel):
     """Ties resolve toward the LOWEST class index — `jnp.argmax` first-max
-    semantics on similarities — even when the duplicates straddle the bc=128
-    tile boundary of the revisited-grid reduction (the strict `<` merge must
-    keep the earlier tile's winner)."""
-    d, c = 512, 300  # 3 class tiles of 128 (two full + one padded)
+    semantics on similarities — inside one full 100-class block, and when the
+    duplicates straddle the bc=128 tile boundary of the revisited-grid
+    reduction (the strict `<` merge must keep the earlier tile's winner)."""
+    d = 512
     q = hv.pack(hv.random_hv(jax.random.PRNGKey(0), 2, d)).reshape(1, 2, d // 32)
-    base = hv.pack(hv.random_hv(jax.random.PRNGKey(1), c, d))
+    base = hv.pack(hv.random_hv(jax.random.PRNGKey(1), 300, d))
     # plant the query itself (distance 0) at several duplicate positions that
     # span different tiles; the reported argmin must always be the first one
-    for dup_positions in [(5, 17), (5, 200), (130, 260), (129, 130, 299)]:
-        p = base
+    cases = [(100, None, dup) for dup in [(5, 17), (0, 99), (31, 32, 98)]] + [
+        (300, bc, dup)  # one 300-class block, or 3 tiles of 128 (one padded)
+        for bc in (None, 128)
+        for dup in [(5, 17), (5, 200), (130, 260), (129, 130, 299)]
+    ]
+    for c, bc, dup_positions in cases:
+        p = base[:c]
         for pos in dup_positions:
             p = p.at[pos].set(q[0, 0])
         pb = p[None]  # [1, C, W]
-        v, i = hamming_topk_banked(q[:, :1], pb, use_kernel=use_kernel,
+        v, i = hamming_topk_banked(q[:, :1], pb, bc=bc, use_kernel=use_kernel,
                                    interpret=True)
         assert int(v[0, 0]) == 0
         assert int(i[0, 0]) == dup_positions[0], (dup_positions, int(i[0, 0]))

@@ -21,7 +21,10 @@ from repro.core.scaleout import ScaleOutConfig, _validate_coarse
 from repro.kernels import common
 from repro.kernels.hamming import hamming_topk_banked
 from repro.kernels.hamming.ops import _streamed_topk_banked
-from repro.kernels.hamming.ref import hamming_topk_k_banked_ref
+from repro.kernels.hamming.ref import (
+    hamming_topk_banked_ref,
+    hamming_topk_k_banked_ref,
+)
 from repro.serving.hdc import centroid_to_class, multicentroid_bank
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -139,12 +142,16 @@ def test_topk_bank_rows_indirection(use_kernel):
     q = hv.pack(hv.random_hv(kq, g * b, d)).reshape(g, b, -1)
     table = hv.pack(hv.random_hv(kp, t * c, d)).reshape(t, c, -1)
     rows = jax.random.randint(kr, (g,), 0, t, dtype=jnp.int32)  # repeats likely
-    got = hamming_topk_banked(
-        q, table, k=k, bank_rows=rows, use_kernel=use_kernel, interpret=True
-    )
-    ref = hamming_topk_k_banked_ref(q, jnp.take(table, rows, axis=0), k)
-    for gx, rx in zip(got, ref):
-        np.testing.assert_array_equal(np.asarray(gx), np.asarray(rx))
+    rows = rows.at[1].set(rows[0])                              # and certain
+    banks = jnp.take(table, rows, axis=0)
+    for kk, ref in [(k, hamming_topk_k_banked_ref(q, banks, k)),
+                    (None, hamming_topk_banked_ref(q, banks))]:
+        got = hamming_topk_banked(
+            q, table, k=kk, bank_rows=rows, use_kernel=use_kernel,
+            interpret=True
+        )
+        for gx, rx in zip(got, ref):
+            np.testing.assert_array_equal(np.asarray(gx), np.asarray(rx))
 
 
 @pytest.mark.parametrize("interpret", [True, False])
@@ -175,6 +182,22 @@ def test_hamming_blocks_policy():
     assert common.hamming_blocks(64, common.TALL_C - 1) == (common.BQ, common.BC)
     assert common.hamming_blocks(64, common.TALL_C, bq=4, bc=32) == (4, 32)
     assert common.hamming_blocks(64, 512, bc=256) == (common.BQ, 256)
+
+
+def test_top1_blocks_policy():
+    # (banks per step, bq, bc) of the MXU top-1; explicit overrides win
+    whype, table1 = (2048, 512, 100, 64), (2048, 64, 100, 16)
+    assert common.top1_blocks(*whype) == (1, 512, 100)     # one bank a step
+    nb, bq, bc = common.top1_blocks(*table1)
+    assert (bq, bc) == (64, 100) and 2048 % nb == 0 and nb > 1
+    assert nb * 64 * 100 * 512 <= common.TOP1_STEP_MACS
+    assert common.top1_blocks(7, 64, 100, 16)[0] in (1, 7)  # divides G
+    assert common.top1_blocks(1, 13, 100, 64)[1] == 16      # B up to x8
+    assert common.top1_blocks(1, 600, 100, 16)[1] == 128    # 640 = 5 x 128
+    assert common.top1_blocks(1, 1024, 100, 16)[1] == common.TOP1_BQ
+    assert common.top1_blocks(1, 8, common.TALL_C - 1, 64)[2] == common.TALL_C - 1
+    assert common.top1_blocks(1, 8, common.TALL_C, 64)[2] == 4 * common.BC
+    assert common.top1_blocks(4, 64, 100, 16, bq=8, bc=32)[1:] == (8, 32)
 
 
 def test_majority_packed_masked_matches_numpy():

@@ -80,6 +80,13 @@ def _kernel_case(name, sds):
         # the ring's search: every (slot, core) bank through the row table
         return (lambda q, p, r: hamming_topk_banked(
             q, p, bank_rows=r, interpret=False), (ring_q, table, rows))
+    if name == "hamming_topk_banked_k1_table1":
+        # Table I's ring: 32 slots x 64 cores of 64 trials, d=512, several
+        # small banks a grid step
+        return (lambda q, p, r: hamming_topk_banked(
+            q, p, bank_rows=r, interpret=False),
+            (sds((32 * 64, 64, 16), u32), sds((64 * 64, C_CORE, 16), u32),
+             sds((32 * 64,), i32)))
     if name == "hamming_topk_banked_k8":
         return (lambda q, p, r: hamming_topk_banked(
             q, p, k=8, bank_rows=r, interpret=False), (ring_q, table, rows))
@@ -107,6 +114,7 @@ def _kernel_case(name, sds):
 
 @pytest.mark.parametrize("name", [
     "hamming_topk_banked_k1",
+    "hamming_topk_banked_k1_table1",
     "hamming_topk_banked_k8",
     "hamming_search_banked",
     "hamming_search",
