@@ -289,7 +289,9 @@ def _local_search(q: jax.Array, protos: jax.Array, use_kernels: bool) -> jax.Arr
 # The OTA bundle, the RX fan-out, the shard search and the global top-1 each
 # run under a `jax.named_scope` (ota_bundle, rx_copies, search, top1_gather):
 # the compiled step's ops carry it in their op_name metadata, so device time
-# is attributed to the stage it belongs to; values are unchanged.
+# is attributed to the stage it belongs to; values are unchanged. Inside
+# ota_bundle, the cross-chip calls alone (the vote all-reduce, reduce-scatter
+# and all-gather, the combo psum) run under a nested `vote_exchange` scope.
 # ---------------------------------------------------------------------------
 
 def _tx_ids(cfg: ScaleOutConfig, e_per: int):
@@ -366,22 +368,24 @@ def _ota_bundle(cfg: ScaleOutConfig, chan, model_size: int, e_per: int,
         )
         cdt = (jnp.int8 if cfg.m_tx <= 7
                else jnp.int16 if cfg.m_tx <= 15 else jnp.int32)
-        return jax.lax.psum(partial.astype(cdt), "model").astype(
-            jnp.int32)  # [..., d] combo index
+        with jax.named_scope("vote_exchange"):
+            combo = jax.lax.psum(partial.astype(cdt), "model")
+        return combo.astype(jnp.int32)  # [..., d] combo index
     # bipolar majority votes; abstaining slots (g >= m_tx) vote exact 0
     votes = jnp.sum(
         jnp.where(active, 2 * q_bits.astype(jnp.int8) - 1, 0), axis=-2
     ).astype(jnp.int8)
     if cfg.collective in ("psum", "psum_packed"):
-        if cfg.collective == "psum":  # paper-faithful: ONE all-reduce
-            tally = jax.lax.psum(votes, "model")
-        else:  # guard-bit packed votes sized by the M live voters:
-            # ONE uint32 psum, bit-identical tally
-            tally = collectives.packed_vote_allreduce(
-                votes, "model", group_size=model_size, e_per=e_per,
-                n_active=cfg.m_act, local_active=n_act_local,
-                total_active=total_active,
-            )
+        with jax.named_scope("vote_exchange"):
+            if cfg.collective == "psum":  # paper-faithful: ONE all-reduce
+                tally = jax.lax.psum(votes, "model")
+            else:  # guard-bit packed votes sized by the M live voters:
+                # ONE uint32 psum, bit-identical tally
+                tally = collectives.packed_vote_allreduce(
+                    votes, "model", group_size=model_size, e_per=e_per,
+                    n_active=cfg.m_act, local_active=n_act_local,
+                    total_active=total_active,
+                )
         bundled_bits = (tally > 0).astype(jnp.uint8)  # even-M ties -> 0
         return hv.pack(bundled_bits) if packed else bundled_bits
     elif cfg.collective == "rs_ag":
@@ -392,27 +396,31 @@ def _ota_bundle(cfg: ScaleOutConfig, chan, model_size: int, e_per: int,
             # the gathered uint32 words ARE the bundled packed query —
             # no unpack/repack round-trip after the collective.
             assert d % (model_size * hv.WORD) == 0, (d, model_size)
+            with jax.named_scope("vote_exchange"):
+                part = collectives.packed_vote_psum_scatter(
+                    votes, "model", group_size=model_size, e_per=e_per,
+                    n_active=cfg.m_act, local_active=n_act_local,
+                    total_active=total_active,
+                )
+            words = hv.pack((part > 0).astype(jnp.uint8))  # [..., W/S]
+            with jax.named_scope("vote_exchange"):
+                return jax.lax.all_gather(
+                    words, "model", axis=words.ndim - 1, tiled=True
+                )
+        assert d % (model_size * 8) == 0, (d, model_size)
+        with jax.named_scope("vote_exchange"):
             part = collectives.packed_vote_psum_scatter(
                 votes, "model", group_size=model_size, e_per=e_per,
                 n_active=cfg.m_act, local_active=n_act_local,
                 total_active=total_active,
             )
-            words = hv.pack((part > 0).astype(jnp.uint8))  # [..., W/S]
-            return jax.lax.all_gather(
-                words, "model", axis=words.ndim - 1, tiled=True
-            )
-        assert d % (model_size * 8) == 0, (d, model_size)
-        part = collectives.packed_vote_psum_scatter(
-            votes, "model", group_size=model_size, e_per=e_per,
-            n_active=cfg.m_act, local_active=n_act_local,
-            total_active=total_active,
-        )
         bits = (part > 0).astype(jnp.uint8)          # [..., d/S]
         w = bits.reshape(bits.shape[:-1] + (-1, 8))
         packed8 = jnp.sum(w << jnp.arange(8, dtype=jnp.uint8), axis=-1).astype(jnp.uint8)
-        allbytes = jax.lax.all_gather(
-            packed8, "model", axis=packed8.ndim - 1, tiled=True
-        )
+        with jax.named_scope("vote_exchange"):
+            allbytes = jax.lax.all_gather(
+                packed8, "model", axis=packed8.ndim - 1, tiled=True
+            )
         return (
             (allbytes[..., None] >> jnp.arange(8, dtype=jnp.uint8)) & 1
         ).reshape(bits.shape[:-1] + (d,)).astype(jnp.uint8)

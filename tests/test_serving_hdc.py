@@ -184,6 +184,71 @@ def test_mt_serve_multidevice_packed_collectives():
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
 
 
+def test_engine_ring_on_four_chips_equals_one_chip_and_the_reference():
+    """The ``whype4`` layout at toy sizes: the HDCEngine ring on a (1, 4)
+    mesh (a quarter of the cores and classes a chip, the vote tally
+    exchanged between the chips) gives, trial by trial, the same
+    (pred, maxsim) as on a (1, 1) mesh for the same seeded banks, payloads
+    and keys, and both equal the benchmark's plain reference: the four
+    chips' shares together make the one-chip answer."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = SRC
+    bench = os.path.join(os.path.dirname(SRC), "bench")
+    code = f"""
+    import sys
+    sys.path.insert(0, {bench!r})
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    import deploy, reference
+    from repro import phy
+    from repro.compat import make_mesh
+    from repro.serving import HDCEngine
+    s = dict(n_classes=256, dim=256, m_tx=3, n_rx_cores=64, snr_db=7.0,
+             representation="packed", collective="psum_packed", channel="bsc",
+             noise="bitplane", noise_planes=16, tenants=4, slots=8,
+             trials_per_request=8, payloads_per_tenant=2)
+    seed = 2**32 + 1505
+    cfg = deploy.service_config(s)
+    banks = deploy.make_banks(seed, s)
+    ber = jnp.linspace(0.01, 0.08, s["n_rx_cores"])
+    state = phy.state_from_ber(ber, s["m_tx"])
+    rng = np.random.default_rng(7)
+    keys = [k for k in rng.integers(0, 2**32, (8, 2), dtype=np.uint32)]
+    tenants = [3, 0, 1, 3, 2, 0, 1, 2]
+    out = {{}}
+    for model in (1, 4):
+        mesh = make_mesh((1, model), ("data", "model"),
+                         devices=jax.devices()[:model])
+        eng = HDCEngine(mesh, cfg, jax.device_put(state, NamedSharding(mesh, P())),
+                        num_slots=8, max_tenants=4)
+        for t in range(4):
+            eng.registry.onboard(t, banks[t])
+        classes, queries = deploy.make_payloads(seed, s, banks, model)
+        st = eng.admit_many(eng.init_state(),
+                            [queries[t, i % 2] for i, t in enumerate(tenants)],
+                            tenants, list(range(8)), keys)
+        _, (pred, sim) = eng.step(eng.params, st)
+        out[model] = np.asarray(pred), np.asarray(sim)
+    np.testing.assert_array_equal(out[4][0], out[1][0])
+    np.testing.assert_array_equal(out[4][1], out[1][1])
+    thr = jnp.asarray(reference.flip_threshold(np.asarray(ber), 16))
+    for i, t in enumerate(tenants):
+        ref_p, ref_s = reference.serve(
+            banks[t], classes[t, i % 2], jnp.asarray(keys[i]), thr,
+            n_cores=64, planes=16, chunk=16)
+        np.testing.assert_array_equal(out[4][0][i], np.asarray(ref_p))
+        np.testing.assert_array_equal(out[4][1][i], np.asarray(ref_s))
+    assert len(set(out[4][0].ravel().tolist())) > 8
+    print("OK")
+    """
+    r = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(code)],
+        capture_output=True, text=True, timeout=600, env=env,
+    )
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
+
+
 # ---------------------------------------------------------------------------
 # living channels: adaptive engine + link controller
 # ---------------------------------------------------------------------------

@@ -198,3 +198,40 @@ def test_ring_step_fits_one_v5e(topo, monkeypatch):
     text = compiled.as_text()
     for scope in ("ota_bundle", "rx_copies", "search", "top1_gather"):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
+
+
+def test_four_chip_ring_step_fits_each_v5e(topo, monkeypatch):
+    """The ``whype4`` ring step (8 slots x 512 trials, 64 tenants resident,
+    the 1,024 cores over the (1, 4) mesh of a four-chip host) compiles: its
+    search is a Mosaic kernel, each chip's HBM fits 16 GB, and its vote
+    all-reduce runs under the ``vote_exchange`` scope nested in
+    ``ota_bundle``."""
+    from repro.core import scaleout
+    from repro.kernels import common
+
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
+    chips, slots, tenants = 4, 8, 64
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                ("data", "model"))
+    cfg = scaleout.ScaleOutConfig(
+        n_classes=C, dim=D, m_tx=3, n_rx_cores=RX, batch=RING_TRIALS,
+        representation="packed", collective="psum_packed", channel="bsc",
+        noise="bitplane")
+    sds, state, e_per = _serve_structs(mesh, cfg)
+
+    def sharded(shape, dt, spec):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+
+    compiled = scaleout.make_mt_ota_serve(mesh, cfg).lower(
+        sharded((tenants, C, W), jnp.uint32, P(None, "model", None)),
+        sharded((slots, RING_TRIALS, chips, e_per, W), jnp.uint32,
+                P(None, None, "model", None, None)),
+        sds((slots,), jnp.int32), state, sds((slots, 2), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    reduce_ops = re.findall(r"= \S+ all-reduce\(.*", text)
+    assert reduce_ops and all('ota_bundle/vote_exchange/' in op
+                              for op in reduce_ops)
