@@ -269,6 +269,14 @@ def majority_packed_masked(hvs: jax.Array, mask: jax.Array) -> jax.Array:
     return _bitsliced_gt_traced(planes, (n // 2).astype(jnp.uint32))
 
 
+def bernoulli_threshold(p: jax.Array | float, precision: int = 16) -> jax.Array:
+    """The `precision`-bit uniform's threshold of `bernoulli_words`:
+    round(p * 2^precision) as uint32, clipped to [0, 2^precision - 1]."""
+    return jnp.clip(
+        jnp.round(jnp.asarray(p, jnp.float32) * (2**precision)), 0, 2**precision - 1
+    ).astype(jnp.uint32)
+
+
 def bernoulli_words(
     key: jax.Array, p: jax.Array | float, shape: tuple[int, ...], precision: int = 16
 ) -> jax.Array:
@@ -283,9 +291,7 @@ def bernoulli_words(
     `flip_bits`; use `flip_bits_packed` when identity matters).
     """
     planes = jax.random.bits(key, (precision,) + tuple(shape), dtype=jnp.uint32)
-    t = jnp.clip(
-        jnp.round(jnp.asarray(p, jnp.float32) * (2**precision)), 0, 2**precision - 1
-    ).astype(jnp.uint32)
+    t = bernoulli_threshold(p, precision)
     lt = jnp.zeros(shape, jnp.uint32)
     eq = jnp.full(shape, _FULL, jnp.uint32)
     for i in reversed(range(precision)):

@@ -429,13 +429,14 @@ def _ota_bundle(cfg: ScaleOutConfig, chan, model_size: int, e_per: int,
 
 @jax.named_scope("rx_copies")
 def _rx_fanout(cfg: ScaleOutConfig, chan, cores_per_shard: int, tx,
-               q_bundled, state, kq):
+               q_bundled, state, kqs):
     """Per-core decode through the PHY tier: each of this shard's IMC cores
-    receives its own noisy copy of the bundled query."""
-    return chan.rx_copies(
-        kq, q_bundled, state, rx_base=tx * cores_per_shard,
+    receives its own noisy copy of each slot's bundled query.
+    q_bundled [N, B, d|W], kqs [N, 2] (a slot's key) -> [N, n_core, B, d|W]."""
+    return chan.rx_copies_slots(
+        kqs, q_bundled, state, rx_base=tx * cores_per_shard,
         n_cores=cores_per_shard, packed=cfg.packed, dim=cfg.dim,
-        noise=cfg.noise, planes=cfg.noise_planes,
+        noise=cfg.noise, planes=cfg.noise_planes, use_kernel=cfg.use_kernels,
     )
 
 
@@ -925,8 +926,8 @@ def make_ota_serve(
             q_rx = _sparse_rx_fanout(cfg, cores_per_shard, tx, q_bundled,
                                      state, kq)
         else:
-            q_rx = _rx_fanout(cfg, chan, cores_per_shard, tx, q_bundled,
-                              state, kq)
+            q_rx = _rx_fanout(cfg, chan, cores_per_shard, tx, q_bundled[None],
+                              state, kq[None])[0]
         # [n_core, B_l, d|W] -> each core searches its class sub-shard
         stuck = None
         if fstate is not None:
@@ -1233,10 +1234,8 @@ def make_mt_ota_serve(mesh: Mesh, cfg: ScaleOutConfig, process=None,
         # --- PHY fan-out per slot with the slot's own key (RNG identity) ---
         dpos = _dpos(mesh, dp)
         kqs = jax.vmap(lambda k: jax.random.fold_in(k, dpos))(keys)
-        q_rx = jax.vmap(
-            lambda qb, kq: _rx_fanout(cfg, chan, cores_per_shard, tx, qb,
-                                      state, kq)
-        )(q_bundled, kqs)  # [N, n_core, B_l, d|W]
+        q_rx = _rx_fanout(cfg, chan, cores_per_shard, tx, q_bundled, state,
+                          kqs)  # [N, n_core, B_l, d|W]
         stuck = None
         if fstate is not None:
             q_rx, qmask = _apply_rx_faults(fstate, tx, cores_per_shard, q_rx,

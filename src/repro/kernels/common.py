@@ -100,3 +100,31 @@ def top1_blocks(
     want = max(1, TOP1_STEP_MACS // (bq * bc * 32 * w))
     nb = max(k for k in range(1, min(g, want) + 1) if g % k == 0)
     return nb, bq, bc
+
+
+# The RX fan-out's bitplane kernel is bound by the VPU (16 threefry hashes a
+# mask word), so its blocks only have to amortize a grid step: a block holds
+# whole lane-dense core planes of up to ``BITPLANE_RB`` rows and about
+# ``BITPLANE_STEP_WORDS`` output words.
+BITPLANE_RB = 512
+BITPLANE_STEP_WORDS = 2**18
+
+
+def bitplane_blocks(n: int, n_core: int, b: int, w: int) -> tuple[int, int, int]:
+    """Resolve (slots, cores, rows) a block for the bitplane copies of ``n``
+    slots x ``n_core`` cores of [b, w] words, each core's plane read as
+    rows of 128 words.
+
+    - rows: the plane's rows rounded up to 8, split evenly into blocks of at
+      most ``BITPLANE_RB`` rows (Table I's plane is 8 rows, WHYPE's 256);
+    - cores, then slots: as many whole planes as keep the block near
+      ``BITPLANE_STEP_WORDS`` (8 cores of one slot at WHYPE, all 64 cores
+      of 4 slots at Table I). They need not divide ``n_core`` or ``n``: the
+      edge block computes on padding that is not written.
+    """
+    rows = cdiv(cdiv(b * w, 128), 8)
+    rb = 8 * cdiv(rows, cdiv(rows * 8, BITPLANE_RB))
+    pairs = max(1, BITPLANE_STEP_WORDS // (rb * 128))
+    cb = min(n_core, pairs)
+    sb = min(n, max(1, pairs // cb))
+    return sb, cb, rb

@@ -201,6 +201,18 @@ class Channel:
                   *, packed: bool, dim: int, noise: str, planes: int) -> jax.Array:
         raise NotImplementedError
 
+    def rx_copies_slots(self, keys, reduced, state: ChannelState, rx_base,
+                        n_cores: int, *, packed: bool, dim: int, noise: str,
+                        planes: int, use_kernel: bool) -> jax.Array:
+        """`rx_copies` for N slots, each with its own key: keys [N, 2],
+        reduced [N, ...] -> [N, n_cores, ...]. A tier with a kernel for the
+        whole slot batch overrides this; the default maps over the slots."""
+        return jax.vmap(
+            lambda k, r: self.rx_copies(k, r, state, rx_base, n_cores,
+                                        packed=packed, dim=dim, noise=noise,
+                                        planes=planes)
+        )(keys, reduced)
+
 
 class IdealChannel(Channel):
     """Error-free link: every core receives the exact majority bundle."""
@@ -237,6 +249,25 @@ class BSCChannel(Channel):
             return collectives.ota_noise(k, reduced, ber)
 
         return jax.vmap(one)(jnp.arange(n_cores), state.ber)
+
+    def rx_copies_slots(self, keys, reduced, state, rx_base, n_cores,
+                        *, packed, dim, noise, planes, use_kernel):
+        """Packed bitplane copies of every slot run as one Pallas kernel
+        (`kernels.bsc`), bit for bit `rx_copies` mapped over the slots: the
+        same per-core keys and thresholds, the 16 random planes kept in
+        vregs. The other modes map `rx_copies` over the slots."""
+        if not (packed and noise == "bitplane" and use_kernel):
+            return super().rx_copies_slots(
+                keys, reduced, state, rx_base, n_cores, packed=packed,
+                dim=dim, noise=noise, planes=planes, use_kernel=use_kernel)
+        from repro.kernels.bsc import bsc_bitplane_copies
+
+        cores = rx_base + jnp.arange(n_cores)
+        core_keys = jax.vmap(
+            lambda k: jax.vmap(lambda i: jax.random.fold_in(k, i))(cores)
+        )(keys)  # [N, n_cores, 2]
+        return bsc_bitplane_copies(core_keys, reduced, state.ber,
+                                   precision=planes)
 
 
 class SymbolChannel(Channel):

@@ -223,3 +223,129 @@ def test_pallas_flash_attention_sweep(s, h, kh, d, win, causal, bq, bk):
                               block_q=bq, block_k=bk, interpret=True)
     want = flash_fwd_ref(q, k, v, causal=causal, window=win, block_q=bq, block_k=bk)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# RX fan-out: each core's bitplane-noisy copy (`kernels.bsc`)
+# ---------------------------------------------------------------------------
+
+from jax.extend import core as _jcore
+
+from repro import phy
+from repro.kernels import common
+from repro.kernels.bsc import bsc_bitplane_copies
+from repro.kernels.bsc.kernel import LANES, bsc_bitplane_pallas
+
+# the largest BER the 16-bit threshold holds: every lane flips but u = 2^16 - 1
+EDGE_BER = 1.0 - 2.0**-16
+
+
+@pytest.mark.parametrize("n,n_core,b,d,rx_base,blocks", [
+    (3, 4, 8, 512, 0, None),        # the ring (slots), W=16, the policy's blocks
+    (3, 5, 8, 2048, 7, (2, 2, 8)),  # W=64; 5 cores and 3 slots in blocks of 2
+    (1, 6, 40, 2048, 64, (1, 4, 8)),  # standalone (one slot), 3 row blocks
+    (1, 3, 13, 512, 5, None),       # B*W = 208 words: the last row padded
+])
+def test_bsc_bitplane_copies_equal_the_xla_path(monkeypatch, n, n_core, b, d,
+                                                rx_base, blocks):
+    """`BSCChannel.rx_copies_slots` with the kernel equals, bit for bit, the
+    vmap of `rx_copies` over the slots (`collectives.ota_noise_packed` in
+    bitplane mode, 16 planes), cores keyed ``fold_in(key, rx_base + i)``;
+    BER 0 and 1 - 2^-16 on the edge cores."""
+    if blocks is not None:
+        monkeypatch.setattr(common, "bitplane_blocks", lambda *a: blocks)
+    w = d // 32
+    k1, k2, k3 = jax.random.split(jax.random.fold_in(KEY, n_core * d + b), 3)
+    words = jax.random.bits(k1, (n, b, w), jnp.uint32)
+    keys = jax.random.bits(k2, (n, 2), jnp.uint32)
+    ber = jax.random.uniform(k3, (n_core,), maxval=0.5)
+    ber = ber.at[0].set(0.0).at[-1].set(EDGE_BER)
+    state = phy.state_from_ber(ber, 3)
+    chan = phy.get_channel("bsc")
+    kw = dict(rx_base=jnp.int32(rx_base), n_cores=n_core, packed=True, dim=d,
+              noise="bitplane", planes=16)
+    got = chan.rx_copies_slots(keys, words, state, use_kernel=True, **kw)
+    want = chan.rx_copies_slots(keys, words, state, use_kernel=False, **kw)
+    assert got.shape == (n, n_core, b, w)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got[:, 0]),
+                                  np.broadcast_to(np.asarray(words)[:, None],
+                                                  (n, 1, b, w))[:, 0])
+    flipped = np.unpackbits(np.asarray(got[:, -1] ^ words).view(np.uint8)).mean()
+    assert flipped > 0.999
+
+
+def test_bsc_bitplane_copies_at_eight_planes():
+    """The control's precision (8 planes) runs the kernel, bit for bit."""
+    k1, k2 = jax.random.split(KEY)
+    words = jax.random.bits(k1, (2, 8, 16), jnp.uint32)
+    keys = jax.random.bits(k2, (2, 3, 2), jnp.uint32)
+    ber = jnp.array([0.01, 0.2, 0.37])
+    got = bsc_bitplane_copies(keys, words, ber, precision=8)
+    want = bsc_bitplane_copies(keys, words, ber, precision=8, use_kernel=False)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_bitplane_blocks_policy():
+    # (slots, cores, rows) a block at the cells' shapes: whole lane-dense
+    # planes, about BITPLANE_STEP_WORDS a block
+    assert common.bitplane_blocks(2, 1024, 512, 64) == (1, 8, 256)   # whype
+    assert common.bitplane_blocks(32, 64, 64, 16) == (4, 64, 8)      # table1
+    assert common.bitplane_blocks(8, 256, 512, 64) == (1, 8, 256)    # whype4
+    assert common.bitplane_blocks(1, 3, 13, 16) == (1, 3, 8)
+    # planes taller than BITPLANE_RB rows split evenly, in multiples of 8
+    assert common.bitplane_blocks(1, 4, 4096, 64) == (1, 4, common.BITPLANE_RB)
+    assert common.bitplane_blocks(1, 4, 600 * 8, 16)[2] == 304  # 600 rows: 2 x 304
+    for shape in [(2, 1024, 512, 64), (32, 64, 64, 16), (5, 7, 40, 64)]:
+        sb, cb, rb = common.bitplane_blocks(*shape)
+        assert rb % 8 == 0 and sb * cb * rb * LANES <= max(
+            common.BITPLANE_STEP_WORDS, rb * LANES)
+
+
+def _eqn_count(jaxpr) -> int:
+    """Equations of a jaxpr, counting those of every nested jaxpr."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, _jcore.ClosedJaxpr):
+                    n += _eqn_count(sub.jaxpr)
+                elif isinstance(sub, _jcore.Jaxpr):
+                    n += _eqn_count(sub)
+    return n
+
+
+def _kernel_body_eqns(n, n_core, b, w, blocks=None) -> int:
+    sb, cb, rb = blocks or common.bitplane_blocks(n, n_core, b, w)
+    rows = common.cdiv(common.cdiv(b * w, LANES), rb) * rb
+    jaxpr = jax.make_jaxpr(
+        lambda kt, x: bsc_bitplane_pallas(kt, x, precision=16, plane_words=b * w,
+                                          sb=sb, cb=cb, rb=rb)
+    )(jax.ShapeDtypeStruct((n, n_core, 3, LANES), jnp.uint32),
+      jax.ShapeDtypeStruct((n, rows, LANES), jnp.uint32)).jaxpr
+
+    def find(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for v in eqn.params.values():
+                if isinstance(v, _jcore.ClosedJaxpr) and (hit := find(v.jaxpr)):
+                    return hit
+        return None
+
+    return _eqn_count(find(jaxpr).params["jaxpr"])
+
+
+def test_bsc_bitplane_kernel_body_does_not_grow_with_its_block():
+    """The kernel's traced body has one size whatever its block holds: Table
+    I's block of 4 slots x 64 cores of 8 rows, WHYPE's of 8 cores of 256 rows
+    and a one-pair block trace to the same equations. A body that looped in
+    Python over its block's slots or cores would grow with them, and every
+    process traces and lowers it again before the compile cache is read."""
+    table1 = _kernel_body_eqns(32, 64, 64, 16)
+    whype = _kernel_body_eqns(2, 1024, 512, 64)
+    one_pair = _kernel_body_eqns(1, 1, 8, 128, blocks=(1, 1, 8))
+    big = _kernel_body_eqns(8, 64, 64, 16, blocks=(8, 64, 8))
+    assert table1 == whype == one_pair == big
+    assert table1 < 2500  # 16 unrolled planes of ~125 ops each

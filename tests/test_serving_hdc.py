@@ -249,6 +249,73 @@ def test_engine_ring_on_four_chips_equals_one_chip_and_the_reference():
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
 
 
+def test_engine_ring_with_the_rx_kernel_equals_the_reference():
+    """The HDCEngine ring step with the RX fan-out kernel on (packed, bsc,
+    16-plane bitplane, one chip) answers trial by trial as the benchmark's
+    plain reference does, and as the same ring with every kernel off; the
+    bitplane kernel is the one that built the copies."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC
+    bench = os.path.join(os.path.dirname(SRC), "bench")
+    code = f"""
+    import dataclasses, sys
+    sys.path.insert(0, {bench!r})
+    import jax, jax.numpy as jnp, numpy as np
+    import deploy, reference
+    from repro import phy
+    from repro.compat import make_mesh
+    from repro.kernels.bsc import ops as bsc_ops
+    from repro.serving import HDCEngine
+    launches = []
+    kernel = bsc_ops.bsc_bitplane_pallas
+    def counted(*a, **kw):
+        launches.append(kw["sb"])
+        return kernel(*a, **kw)
+    bsc_ops.bsc_bitplane_pallas = counted
+    s = dict(n_classes=512, dim=512, m_tx=3, n_rx_cores=32, snr_db=7.0,
+             representation="packed", collective="psum_packed", channel="bsc",
+             noise="bitplane", noise_planes=16, tenants=3, slots=4,
+             trials_per_request=16, payloads_per_tenant=1)
+    seed = 2**33 + 17
+    cfg = deploy.service_config(s)
+    assert cfg.use_kernels
+    banks = deploy.make_banks(seed, s)
+    ber = jnp.linspace(0.0, 0.3, s["n_rx_cores"])
+    state = phy.state_from_ber(ber, s["m_tx"])
+    keys = list(np.random.default_rng(11).integers(0, 2**32, (4, 2),
+                                                   dtype=np.uint32))
+    tenants = [2, 0, 2, 1]
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    classes, queries = deploy.make_payloads(seed, s, banks, 1)
+    out = []
+    for c in (cfg, dataclasses.replace(cfg, use_kernels=False)):
+        eng = HDCEngine(mesh, c, state, num_slots=4, max_tenants=3)
+        for t in range(3):
+            eng.registry.onboard(t, banks[t])
+        st = eng.admit_many(eng.init_state(), [queries[t, 0] for t in tenants],
+                            tenants, list(range(4)), keys)
+        _, (pred, sim) = eng.step(eng.params, st)
+        out.append((np.asarray(pred), np.asarray(sim)))
+    assert launches, "the ring did not run the bitplane kernel"
+    np.testing.assert_array_equal(out[0][0], out[1][0])
+    np.testing.assert_array_equal(out[0][1], out[1][1])
+    thr = jnp.asarray(reference.flip_threshold(np.asarray(ber), 16))
+    for i, t in enumerate(tenants):
+        ref_p, ref_s = reference.serve(
+            banks[t], classes[t, 0], jnp.asarray(keys[i]), thr,
+            n_cores=32, planes=16, chunk=16)
+        np.testing.assert_array_equal(out[0][0][i], np.asarray(ref_p))
+        np.testing.assert_array_equal(out[0][1][i], np.asarray(ref_s))
+    assert len(set(out[0][0].ravel().tolist())) > 8
+    print("OK")
+    """
+    r = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(code)],
+        capture_output=True, text=True, timeout=600, env=env,
+    )
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
+
+
 # ---------------------------------------------------------------------------
 # living channels: adaptive engine + link controller
 # ---------------------------------------------------------------------------

@@ -87,6 +87,12 @@ def _kernel_case(name, sds):
             q, p, bank_rows=r, interpret=False),
             (sds((32 * 64, 64, 16), u32), sds((64 * 64, C_CORE, 16), u32),
              sds((32 * 64,), i32)))
+    if name == "bsc_bitplane_copies":
+        # the ring's RX fan-out: every (slot, core)'s bitplane-noisy copy
+        from repro.kernels.bsc import bsc_bitplane_copies
+        return (lambda k, x, p: bsc_bitplane_copies(k, x, p, interpret=False),
+                (sds((SLOTS, RX, 2), u32), sds((SLOTS, RING_TRIALS, W), u32),
+                 sds((RX,), jnp.float32)))
     if name == "hamming_topk_banked_k8":
         return (lambda q, p, r: hamming_topk_banked(
             q, p, k=8, bank_rows=r, interpret=False), (ring_q, table, rows))
@@ -116,6 +122,7 @@ def _kernel_case(name, sds):
     "hamming_topk_banked_k1",
     "hamming_topk_banked_k1_table1",
     "hamming_topk_banked_k8",
+    "bsc_bitplane_copies",
     "hamming_search_banked",
     "hamming_search",
     "sparse_topk_banked",
@@ -198,6 +205,42 @@ def test_ring_step_fits_one_v5e(topo, monkeypatch):
     text = compiled.as_text()
     for scope in ("ota_bundle", "rx_copies", "search", "top1_gather"):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
+
+
+@pytest.mark.parametrize("cell,classes,dim,cores,slots,trials,tenants", [
+    ("table1", 6400, 512, 64, 32, 64, 64),
+    ("whype", C, D, RX, SLOTS, RING_TRIALS, 64),
+])
+def test_ring_step_runs_the_rx_kernel_on_v5e(topo, monkeypatch, cell, classes,
+                                             dim, cores, slots, trials,
+                                             tenants):
+    """The benchmark's one-chip ring steps (``table1``: 32 slots x 64 trials,
+    64 cores, d=512; ``whype``: 2 slots x 512 trials, 1,024 cores, d=2048;
+    64 tenants each) compile with the RX fan-out as the bitplane kernel
+    under the ``rx_copies`` scope, and fit one chip. Logs the temporaries."""
+    from repro.core import scaleout
+    from repro.kernels import common
+
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    cfg = scaleout.ScaleOutConfig(
+        n_classes=classes, dim=dim, m_tx=3, n_rx_cores=cores, batch=trials,
+        representation="packed", collective="psum_packed", channel="bsc",
+        noise="bitplane")
+    sds, state, e_per = _serve_structs(mesh, cfg)
+    w = dim // 32
+    compiled = scaleout.make_mt_ota_serve(mesh, cfg).lower(
+        sds((tenants, classes, w), jnp.uint32),
+        sds((slots, trials, 1, e_per, w), jnp.uint32),
+        sds((slots,), jnp.int32), state, sds((slots, 2), jnp.uint32)).compile()
+    text = compiled.as_text()
+    kernel = re.findall(r"%bsc_bitplane_pallas\S* = .*", text)
+    assert len(kernel) == 1 and 'tpu_custom_call' in kernel[0]
+    assert "/rx_copies/" in kernel[0]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    print(f"{cell} ring step: {mem.temp_size_in_bytes} bytes of temporaries, "
+          f"{mem.argument_size_in_bytes} bytes of arguments")
 
 
 def test_four_chip_ring_step_fits_each_v5e(topo, monkeypatch):
