@@ -28,6 +28,10 @@ Mapping of the paper's architecture onto the production TPU mesh:
   pairs for the global top-1.
 * trials are batched over the ``data`` (and ``pod``) axes.
 
+One builder, ``_build_ota_serve``, holds this dataflow for a batch of slots:
+``make_mt_ota_serve`` is the multi-tenant ring step, and ``make_ota_serve`` is
+its one-slot view.
+
 ``make_wired_serve`` implements the *wired-baseline* dataflow the paper argues
 against: queries are all-gathered to every core (the NoC broadcast), then bundled
 locally — same math, M·(model_size)× the collective bytes. The roofline benchmark
@@ -279,13 +283,15 @@ def _local_search(q: jax.Array, protos: jax.Array, use_kernels: bool) -> jax.Arr
 
 
 # ---------------------------------------------------------------------------
-# serve-step stages (shared by the standalone and multi-tenant serves)
+# serve-step stages of the one OTA serve builder (`_build_ota_serve`)
 #
-# Each stage runs INSIDE the shard_map body on one model shard. They are the
-# verbatim standalone dataflow, generalized to arbitrary leading row dims
-# (axis=-2 encoder sums, shape[:-1] reshapes) so the multi-tenant serve can
-# flatten its [N_slots, B] rows through the same collectives — elementwise
-# over rows, hence bit-identical per row to a standalone serve of that row.
+# Each stage runs INSIDE the shard_map body on one model shard, on the
+# slot-shaped batch [N_slots, B]: the bundle collectives are elementwise over
+# arbitrary leading row dims (axis=-2 encoder sums, shape[:-1] reshapes), so
+# the slots are flattened through them and each row tallies exactly as it
+# would alone; the fan-out draws each slot's copies from its own key; the
+# search keeps per-slot reduction order. A one-slot launch (`make_ota_serve`)
+# and slot s of an N-slot launch are therefore bit-identical.
 # The OTA bundle, the RX fan-out, the shard search and the global top-1 each
 # run under a `jax.named_scope` (ota_bundle, rx_copies, search, top1_gather):
 # the compiled step's ops carry it in their op_name metadata, so device time
@@ -325,7 +331,7 @@ def _ota_bundle(cfg: ScaleOutConfig, chan, model_size: int, e_per: int,
     q_mine [..., e_per, d|W] (any leading row dims) -> bundled query
     [..., d|W] (or [..., d] int32 combo index for wire == "combo"). Elementwise
     over the leading rows, so flattened multi-slot batches tally bit-identically
-    to per-row standalone calls.
+    to each row bundled alone.
 
     ``fstate`` (a `faults.FaultState`, TX-side leaves replicated) erases dead
     or dropped encoder slots from the superposition. Vote wire: the erased
@@ -473,8 +479,9 @@ def _sparse_bundle(cfg: ScaleOutConfig, chan, model_size: int, e_per: int,
 
 
 def _sparse_rx_fanout(cfg: ScaleOutConfig, cores_per_shard: int, tx,
-                      q_bundled, state, kq):
+                      q_bundled, state, kqs):
     """Per-core sparse decode — the index-list analogue of `_rx_fanout`.
+    q_bundled [N, B, k_max], kqs [N, 2] (a slot's key) -> [N, n_core, B, k_max].
 
     ``ideal`` broadcasts the bundled list; ``bsc`` applies the O(k)
     drop+insert channel (`sparse.flip_bits_sparse`) at each core's
@@ -485,14 +492,18 @@ def _sparse_rx_fanout(cfg: ScaleOutConfig, cores_per_shard: int, tx,
     """
     if cfg.channel == "ideal":
         return jnp.broadcast_to(
-            q_bundled[None], (cores_per_shard,) + q_bundled.shape)
+            q_bundled[:, None],
+            q_bundled.shape[:1] + (cores_per_shard,) + q_bundled.shape[1:])
     rx_base = tx * cores_per_shard
 
-    def one(i, ber):
-        k = jax.random.fold_in(kq, rx_base + i)
-        return sparse.flip_bits_sparse(k, q_bundled, ber, cfg.dim)
+    def slot(q, kq):
+        def one(i, ber):
+            k = jax.random.fold_in(kq, rx_base + i)
+            return sparse.flip_bits_sparse(k, q, ber, cfg.dim)
 
-    return jax.vmap(one)(jnp.arange(cores_per_shard), state.ber)
+        return jax.vmap(one)(jnp.arange(cores_per_shard), state.ber)
+
+    return jax.vmap(slot)(q_bundled, kqs)
 
 
 def _apply_stuck(rows_arr, stuck, d: int, packed: bool, core_axis: int):
@@ -556,19 +567,20 @@ def _group_summaries(cfg: ScaleOutConfig, banks: jax.Array) -> jax.Array:
     return hv.majority_packed(members) if cfg.packed else hv.majority(members)
 
 
-def _coarse_fine_packed(cfg: ScaleOutConfig, banks, q, bank_rows=None):
+def _coarse_fine_packed(cfg: ScaleOutConfig, banks, q, bank_rows):
     """Two-level packed search: coarse top-keep screen over the group
     summaries (ONE fused top-k launch), exact rescore over only the
-    surviving rows. banks [T, C_core, W] (T == G when ``bank_rows`` is None),
-    q [G, B, W] -> (dist, row) of each bank's winner, both [G, B] int32.
+    surviving rows. banks [T, C_core, W], q [G, B, W], bank_rows [G] (query
+    bank g searches table row ``bank_rows[g]``) -> (dist, row) of each
+    bank's winner, both [G, B] int32.
 
     Survivor groups are re-sorted ascending and the rescore minimizes ONE
     ``dist*c_core + row`` int32 key, so ties break toward the lowest class
     row exactly like the flat scan — predictions match the flat path whenever
     the screen recalls the true winner, and keep == n_grp is bit-identical.
-    With ``bank_rows`` the survivor rows are gathered straight from the bank
-    table (advanced indexing), so the expanded [G, C_core, W] view never
-    materializes — the same indirection contract as `hamming_topk_banked`.
+    The survivor rows are gathered straight from the bank table (advanced
+    indexing), so the expanded [G, C_core, W] view never materializes — the
+    same indirection contract as `hamming_topk_banked`.
     """
     gs = cfg.coarse_group
     t, c_core, w = banks.shape
@@ -583,18 +595,18 @@ def _coarse_fine_packed(cfg: ScaleOutConfig, banks, q, bank_rows=None):
     rows = (
         gidx[..., None] * gs + jnp.arange(gs, dtype=jnp.int32)
     ).reshape(g, b_l, keep * gs)
-    bidx = jnp.arange(g, dtype=jnp.int32) if bank_rows is None else bank_rows
-    cand = banks[bidx[:, None, None], rows]           # [G, B, keep*gs, W]
+    cand = banks[bank_rows[:, None, None], rows]      # [G, B, keep*gs, W]
     x = jnp.bitwise_xor(q[:, :, None, :], cand)
     dist = jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=-1)
     key = jnp.min(dist * c_core + rows, axis=-1)      # single-key first-min
     return key // c_core, key % c_core
 
 
-def _coarse_fine_unpacked(cfg: ScaleOutConfig, banks, q, bank_rows=None):
+def _coarse_fine_unpacked(cfg: ScaleOutConfig, banks, q, bank_rows):
     """Unpacked (fp32 bipolar MXU) coarse-to-fine: screen via the summary
     dots, rescore only the surviving rows. banks [T, C_core, d] uint8,
-    q [G, B, d] -> (val f32, row i32) of each bank's winner, both [G, B].
+    q [G, B, d], bank_rows [G] -> (val f32, row i32) of each bank's winner,
+    both [G, B].
 
     `lax.top_k` is stable (ties keep the lower group) and survivors are
     rescored in ascending row order through the same integer-valued fp32
@@ -608,16 +620,14 @@ def _coarse_fine_unpacked(cfg: ScaleOutConfig, banks, q, bank_rows=None):
     n_grp = c_core // gs
     keep = min(cfg.coarse_keep, n_grp)
     summ = _group_summaries(cfg, banks)               # [T, n_grp, d]
-    summ_g = summ if bank_rows is None else jnp.take(summ, bank_rows, axis=0)
     csims = jax.vmap(
         lambda qc, sc: _local_search(qc, sc, cfg.use_kernels)
-    )(q, summ_g)                                      # [G, B, n_grp]
+    )(q, jnp.take(summ, bank_rows, axis=0))           # [G, B, n_grp]
     gidx = jnp.sort(jax.lax.top_k(csims, keep)[1].astype(jnp.int32), axis=-1)
     rows = (
         gidx[..., None] * gs + jnp.arange(gs, dtype=jnp.int32)
     ).reshape(g, b_l, keep * gs)
-    bidx = jnp.arange(g, dtype=jnp.int32) if bank_rows is None else bank_rows
-    cand = banks[bidx[:, None, None], rows]           # [G, B, keep*gs, d]
+    cand = banks[bank_rows[:, None, None], rows]      # [G, B, keep*gs, d]
     qb = 2.0 * q.astype(jnp.float32) - 1.0
     cb = 2.0 * cand.astype(jnp.float32) - 1.0
     sims = jnp.einsum("gbd,gbrd->gbr", qb, cb)        # integer-valued f32
@@ -625,122 +635,6 @@ def _coarse_fine_unpacked(cfg: ScaleOutConfig, banks, q, bank_rows=None):
     star = jnp.argmax(sims, -1)                       # first max among survivors
     row = jnp.take_along_axis(rows, star[..., None], -1)[..., 0]
     return val, row.astype(jnp.int32)
-
-
-@jax.named_scope("search")
-def _shard_top1(cfg: ScaleOutConfig, cores_per_shard: int, tx, q_rx, protos,
-                qmask=None, stuck=None):
-    """This shard's local top-1: each core searches its class sub-shard (with
-    the M permuted banks when cfg.permuted). Returns (val, idx) — similarity
-    value and GLOBAL class index of the shard winner, [B_l] or [B_l, M].
-
-    ``qmask`` [cores_per_shard] bool quarantines cores (True = excluded): a
-    quarantined core's candidates are masked BEFORE the core reduction
-    (distance -> d + 1 / similarity -> -2d), so a degraded receiver can never
-    win the vote for its own classes. An all-False mask is value-identical to
-    qmask=None — the controller's release action costs nothing.
-
-    ``stuck`` = (stuck0, stuck1) [cores_per_shard, W] packed column masks:
-    stored bits forced to their rail in physical array coordinates
-    (`_apply_stuck` — after permuting, so every bank a core stores shares
-    its column faults)."""
-    c_l = protos.shape[0]
-    d = cfg.dim
-    b_l = q_rx.shape[1]
-    packed = cfg.packed
-    assert c_l % cores_per_shard == 0
-    c_core = c_l // cores_per_shard
-    protos_c = protos.reshape(cores_per_shard, c_core, protos.shape[-1])
-
-    if cfg.permuted:
-        # expand each core's memory with the M permuted banks (paper Sec. IV)
-        if packed:
-            # fused top-1 over all (core, bank) pairs: the grid reduces the
-            # class axis in VMEM (and spans the M bank axis too) — the
-            # [G, B_l, c_core] distances never reach HBM; the in-memory
-            # argmax of the IMC macro. argmin == first-max of sims exactly.
-            banks = jnp.stack(
-                [hv.permute_packed(protos_c, m) for m in range(cfg.m_tx)], 1
-            )  # [n_core, M, c_core, W]
-            banks = _apply_stuck(banks, stuck, d, True, 0)
-            g = cores_per_shard * cfg.m_tx
-            q_rep = jnp.broadcast_to(
-                q_rx[:, None], (cores_per_shard, cfg.m_tx) + q_rx.shape[1:]
-            ).reshape(g, b_l, -1)
-            dmin, amin = hamming_topk_banked(
-                q_rep, banks.reshape(g, c_core, -1), use_kernel=cfg.use_kernels
-            )  # each [g, B_l]
-            dmin = jnp.moveaxis(
-                dmin.reshape(cores_per_shard, cfg.m_tx, b_l), 2, 0
-            )  # [B_l, n_core, M]
-            amin = jnp.moveaxis(
-                amin.reshape(cores_per_shard, cfg.m_tx, b_l), 2, 0
-            )
-            if qmask is not None:
-                dmin = jnp.where(qmask[None, :, None], d + 1, dmin)
-            val = d - 2 * jnp.min(dmin, 1)                # [B_l, M]
-            core_star = jnp.argmin(dmin, 1)               # [B_l, M]
-            idx_in_core = jnp.take_along_axis(amin, core_star[:, None, :], 1)[:, 0, :]
-        else:
-            banks = jnp.stack([hv.permute(protos_c, m) for m in range(cfg.m_tx)], 1)
-            # banks: [n_core, M, c_core, d]
-            banks = _apply_stuck(banks, stuck, d, False, 0)
-            sims = jax.vmap(
-                lambda qc, pc: jax.vmap(
-                    lambda bank: _local_search(qc, bank, cfg.use_kernels)
-                )(pc)
-            )(q_rx, banks)  # [n_core, M, B_l, c_core]
-            sims = jnp.moveaxis(sims, 2, 0)  # [B_l, n_core, M, c_core]
-            val_c = jnp.max(sims, -1)
-            idx_c = jnp.argmax(sims, -1).astype(jnp.int32)
-            if qmask is not None:
-                val_c = jnp.where(qmask[None, :, None], -2.0 * d, val_c)
-            val = jnp.max(val_c, 1)                       # [B_l, M]
-            core_star = jnp.argmax(val_c, 1)              # [B_l, M]
-            idx_in_core = jnp.take_along_axis(idx_c, core_star[:, None, :], 1)[:, 0, :]
-        idx = (tx * c_l + core_star * c_core + idx_in_core).astype(jnp.int32)
-    else:
-        protos_c = _apply_stuck(protos_c, stuck, d, packed, 0)
-        if packed or cfg.sparse:
-            if cfg.sparse:
-                # gather-overlap kernel on the raw index lists — integer- and
-                # tie-identical to hamming_topk_banked on the densified
-                # queries, so the packed downstream below is shared verbatim
-                dmin, amin = sparse_topk_banked(
-                    q_rx, protos_c, use_kernel=cfg.use_kernels
-                )
-            elif cfg.coarse_group:
-                dmin, amin = _coarse_fine_packed(cfg, protos_c, q_rx)
-            else:
-                dmin, amin = hamming_topk_banked(
-                    q_rx, protos_c, use_kernel=cfg.use_kernels
-                )  # each [n_core, B_l] — distances reduced in VMEM, not HBM
-            dmin = jnp.moveaxis(dmin, 1, 0)               # [B_l, n_core]
-            amin = jnp.moveaxis(amin, 1, 0)
-            if qmask is not None:
-                dmin = jnp.where(qmask[None, :], d + 1, dmin)
-            val = d - 2 * jnp.min(dmin, -1)               # [B_l]
-            core_star = jnp.argmin(dmin, -1)
-            idx_in_core = jnp.take_along_axis(amin, core_star[:, None], 1)[:, 0]
-        else:
-            if cfg.coarse_group:
-                vg, rg = _coarse_fine_unpacked(cfg, protos_c, q_rx)
-                val_c = jnp.moveaxis(vg, 1, 0)            # [B_l, n_core]
-                idx_c = jnp.moveaxis(rg, 1, 0)
-            else:
-                sims = jax.vmap(
-                    lambda qc, pc: _local_search(qc, pc, cfg.use_kernels)
-                )(q_rx, protos_c)  # [n_core, B_l, c_core]
-                sims = jnp.moveaxis(sims, 1, 0)  # [B_l, n_core, c_core]
-                val_c = jnp.max(sims, -1)
-                idx_c = jnp.argmax(sims, -1).astype(jnp.int32)
-            if qmask is not None:
-                val_c = jnp.where(qmask[None, :], -2.0 * d, val_c)
-            val = jnp.max(val_c, -1)                      # [B_l]
-            core_star = jnp.argmax(val_c, -1)
-            idx_in_core = jnp.take_along_axis(idx_c, core_star[:, None], 1)[:, 0]
-        idx = (tx * c_l + core_star * c_core + idx_in_core).astype(jnp.int32)
-    return val, idx
 
 
 @jax.named_scope("top1_gather")
@@ -808,234 +702,32 @@ def _validate_coarse(cfg: ScaleOutConfig) -> None:
         )
 
 
-def make_ota_serve(
-    mesh: Mesh, cfg: ScaleOutConfig, process=None, faults=None
-) -> Callable[..., tuple[jax.Array, ...]]:
-    """Build the jitted OTA serve step.
-
-    fn(protos [C, dim] u8, queries [B, S_tx, e_per, dim] u8,
-       state phy.ChannelState, key)
-      -> (pred, maxsim); pred [B] int32 (baseline) or [B, m_tx] (permuted).
-    S_tx = model mesh size; e_per = ceil(m_tx / S_tx) encoders per column; global
-    encoder g = column * e_per + j; slots with g >= cfg.m_tx abstain.
-
-    The OTA link itself is the pluggable PHY tier ``cfg.channel``
-    (`repro.phy`): ``bsc`` (default) keeps the historical dataflow — vote
-    tally over the model axis (psum / guard-bit psum_packed / rs_ag), then a
-    per-core BSC at ``state.ber`` — bit-identical to pre-phy serves on the
-    same RNG stream; ``ideal`` skips the noise; ``symbol`` replaces the
-    psum+BSC pair with the physical channel: ONE int32 psum of the
-    per-dimension TX bit-combo (== the constellation superposition, see
-    `phy.channel`), then per-core constellation lookup + AWGN +
-    decision-region decode from the same ChannelState the analytic BER came
-    from. ``state`` is sharded with the cores (`phy.state_spec`).
-
-    With ``cfg.representation == "packed"`` protos/queries are uint32 word arrays
-    ([C, dim/32] / [B, S_tx, e_per, dim/32], see `hv.pack`); the bundled query,
-    the per-core channel noise, the prototype shards and the local search all
-    stay packed (the symbol tier decodes bits, then packs): the top-1 is the
-    fused `hamming_topk_banked` Pallas kernel — one launch over all cores (and
-    permuted banks) that reduces the class axis in VMEM, so the [G, B, C]
-    distance tensor never reaches HBM. The vote tally itself shrinks with
-    ``cfg.collective == "psum_packed"`` (guard-bit field packing sized by the
-    cfg.m_tx ACTIVE voters, ONE uint32 psum, bit-identical to the int8 psum).
-    Predictions and maxsim are bit-identical to the unpacked path on the same
-    RNG stream (cfg.noise="exact") across all collective modes.
-
-    ``process`` (a `phy.ChannelProcess`) switches the serve to the LIVING
-    channel: the built fn becomes
-
-        fn(protos, queries, pstate phy.ProcessState, key, process_key)
-          -> (pred, maxsim, pstate')
-
-    Each call first advances the channel one process step (the per-row RNG is
-    ``fold_in(fold_in(process_key, pstate.t), rx)`` — hold ``process_key``
-    FIXED across steps and the state sequence is reproducible from
-    `phy.rollout` on any mesh), then serves through the evolved
-    ``pstate.chan`` with ``pstate.quarantine`` masking quarantined cores out
-    of the top-1. The carried pytree structure is fixed, so an N-step serve
-    loop compiles ONCE; with `phy.StaticProcess` predictions are bit-identical
-    to the process-free fn on the same keys.
-
-    ``faults`` (a `faults.FaultModel`) threads a `faults.FaultState` through
-    the step — injected hard faults (dead encoders/cores, stuck prototype
-    cells, per-step vote erasures) plus the tolerance machinery (live-voter
-    re-bias, ``serve_rows`` failover, ``rx_mask`` bank exclusion; see
-    `repro.faults`). The built fn appends ``(fstate, fault_key)`` inputs and
-    an evolved ``fstate'`` output after the process arguments:
-
-        fn(protos, queries, state, key, fstate, fault_key)
-          -> (pred, maxsim, fstate')                       # process=None
-        fn(protos, queries, pstate, key, pkey, fstate, fault_key)
-          -> (pred, maxsim, pstate', fstate')              # both
-
-    With `faults.healthy_state` (and any model whose step leaves it healthy)
-    predictions are bit-identical to the faults-free fn on the same keys —
-    fault evolution consumes only ``fault_key``, never the serve stream.
-
-    ``cfg.representation == "sparse"`` serves ultra-sparse queries as sorted
-    int32 index lists ([B, S_tx, e_per, k_max], `core.sparse`): the OTA wire
-    becomes `collectives.sparse_index_allgather` + a local O(k log k) sparse
-    majority (``collective="index_ag"``; psum/psum_packed remain as dense
-    fallbacks for the crossover benchmark), the per-core BSC is the O(k)
-    drop+insert channel, and the top-1 is the gather-overlap
-    `sparse_topk_banked` kernel over the UNCHANGED packed prototype shards
-    [C, dim/32] — predictions are bit-identical to the packed serve at
-    channel="ideal" whenever no bundle saturates k_max. "auto" picks sparse
-    vs packed per (dim, k_max) from the fitted density crossover
-    (`resolve_representation`).
-    """
-    cfg = resolve_representation(cfg)
-    model_size = mesh.axis_sizes[mesh.axis_names.index("model")]
-    assert cfg.n_rx_cores % model_size == 0, (cfg.n_rx_cores, model_size)
-    cores_per_shard = cfg.n_rx_cores // model_size
-    e_per = -(-cfg.m_tx // model_size)
-    dp = _dp_axes(mesh)
-    manual = set(dp) | {"model"}
-    packed = cfg.packed
-    chan = phy.get_channel(cfg.channel)
-    _validate_channel(cfg, chan)
-    _validate_coarse(cfg)
-    if cfg.sparse and (process is not None or faults is not None):
-        raise ValueError(
-            "representation='sparse' does not compose with living-channel "
-            "processes or fault injection (stuck-at / failover state is "
-            "word-addressed dense machinery); use representation='packed'"
-        )
-
-    def serve_core(protos, queries, state, key, qmask, fstate=None):
-        # protos: [C_l, d|W]; queries: [B_l, 1, e_per, d|W];
-        # state: local ChannelState shard (RX-leading leaves [cores_per_shard])
-        tx, gids, n_act_local = _tx_ids(cfg, e_per)
-        q_mine = queries[:, 0]                      # [B_l, e_per, d|W]
-        if cfg.permuted:  # TX g transmits rho^g(q_g) — its signature
-            rho = hv.permute_packed if packed else hv.permute
-            q_mine = jax.vmap(lambda q, g: rho(q, g), in_axes=(1, 0), out_axes=1)(
-                q_mine, gids
-            )
-        # --- the OTA collective over the encoder/model axis ---
-        if cfg.sparse:
-            q_bundled = _sparse_bundle(cfg, chan, model_size, e_per, q_mine,
-                                       gids, n_act_local)
-        else:
-            q_bundled = _ota_bundle(cfg, chan, model_size, e_per, q_mine,
-                                    gids, n_act_local, fstate)
-        # --- per-core decode through the PHY tier ---
-        kq = jax.random.fold_in(key, _dpos(mesh, dp))
-        if cfg.sparse:
-            q_rx = _sparse_rx_fanout(cfg, cores_per_shard, tx, q_bundled,
-                                     state, kq)
-        else:
-            q_rx = _rx_fanout(cfg, chan, cores_per_shard, tx, q_bundled[None],
-                              state, kq[None])[0]
-        # [n_core, B_l, d|W] -> each core searches its class sub-shard
-        stuck = None
-        if fstate is not None:
-            q_rx, qmask = _apply_rx_faults(fstate, tx, cores_per_shard, q_rx,
-                                           qmask, 0)
-            stuck = (fstate.stuck0, fstate.stuck1)
-        val, idx = _shard_top1(cfg, cores_per_shard, tx, q_rx, protos, qmask,
-                               stuck)
-        # --- global top-1: tiny (value, index) all-gather over the cores ---
-        return _gather_top1(cfg, val, idx)
-
-    dp_spec = dp if len(dp) > 1 else (dp[0] if dp else None)
-    if process is None and faults is None:
-        def body(protos, queries, state, key):
-            return serve_core(protos, queries, state, key, None)
-
-        in_specs = (
-            P("model", None),                 # prototype shards (the IMC cores)
-            P(dp_spec, "model", None, None),  # per-encoder queries
-            phy.state_spec("model"),          # per-core channel state
-            P(),                              # key
-        )
-        out_specs = (P(dp_spec), P(dp_spec))
-    elif faults is None:
-        def body(protos, queries, pstate, key, pkey):
-            tx = jax.lax.axis_index("model")
-            # evolve the channel one step, THEN serve through the live state
-            pstate = process.step(pkey, pstate, rx_base=tx * cores_per_shard)
-            pred, maxsim = serve_core(protos, queries, pstate.chan, key,
-                                      pstate.quarantine)
-            return pred, maxsim, pstate
-
-        in_specs = (
-            P("model", None),
-            P(dp_spec, "model", None, None),
-            phy.pstate_spec("model"),         # per-core process state
-            P(),                              # serve key
-            P(),                              # process key (fixed across steps)
-        )
-        out_specs = (P(dp_spec), P(dp_spec), phy.pstate_spec("model"))
-    elif process is None:
-        def body(protos, queries, state, key, fstate, fkey):
-            tx = jax.lax.axis_index("model")
-            # evolve the faults one step, THEN serve through the live state
-            fstate = faults.step(fkey, fstate, rx_base=tx * cores_per_shard)
-            pred, maxsim = serve_core(protos, queries, state, key, None,
-                                      fstate)
-            return pred, maxsim, fstate
-
-        in_specs = (
-            P("model", None),
-            P(dp_spec, "model", None, None),
-            phy.state_spec("model"),
-            P(),                              # serve key
-            faultlib.fstate_spec("model"),    # per-core fault state
-            P(),                              # fault key (fixed across steps)
-        )
-        out_specs = (P(dp_spec), P(dp_spec), faultlib.fstate_spec("model"))
-    else:
-        def body(protos, queries, pstate, key, pkey, fstate, fkey):
-            tx = jax.lax.axis_index("model")
-            pstate = process.step(pkey, pstate, rx_base=tx * cores_per_shard)
-            fstate = faults.step(fkey, fstate, rx_base=tx * cores_per_shard)
-            pred, maxsim = serve_core(protos, queries, pstate.chan, key,
-                                      pstate.quarantine, fstate)
-            return pred, maxsim, pstate, fstate
-
-        in_specs = (
-            P("model", None),
-            P(dp_spec, "model", None, None),
-            phy.pstate_spec("model"),
-            P(),                              # serve key
-            P(),                              # process key
-            faultlib.fstate_spec("model"),
-            P(),                              # fault key
-        )
-        out_specs = (P(dp_spec), P(dp_spec), phy.pstate_spec("model"),
-                     faultlib.fstate_spec("model"))
-
-    fn = compat.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        axis_names=manual,
-        check_vma=False,
-    )
-    return jax.jit(fn)
-
-
 @jax.named_scope("search")
 def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
                       q_rx, store, rows, qmask=None, stuck=None):
-    """Slot-batched local top-1: slot s searches tenant bank ``rows[s]`` of the
-    resident store. ONE `hamming_topk_banked` launch covers every
-    (slot, core[, permuted bank]) — the G axis of the kernel grid — via the
-    ``bank_rows`` indirection (packed) or a row gather (unpacked MXU path);
-    never a vmap over the kernel (its revisited-tile running-min is not
-    vmap-safe). Per-slot reductions keep the standalone [B, core(, M), class]
-    axis order, so ties break identically to `_shard_top1` on that slot alone.
+    """This shard's local top-1, slot-batched: slot s searches tenant bank
+    ``rows[s]`` of the resident store, each core its class sub-shard (with
+    the M permuted banks when cfg.permuted). ONE `hamming_topk_banked`
+    launch covers every (slot, core[, permuted bank]) — the G axis of the
+    kernel grid — via the ``bank_rows`` indirection (packed), a row gather
+    (unpacked MXU path) or the slots' banks taken from the store (sparse:
+    `sparse_topk_banked` has no indirection); never a vmap over the kernel
+    (its revisited-tile running-min is not vmap-safe). Each slot is reduced
+    in [B, core(, M), class] axis order, so its ties break as they would in
+    a one-slot search.
 
-    q_rx [N, n_core, B_l, d|W]; store [T, C_l, d|W]; rows [N] int32.
-    ``qmask`` [cores_per_shard] bool quarantines cores exactly as in
-    `_shard_top1` (masked before the core reduction; all slots share the one
-    physical link, so one mask covers them all). ``stuck`` applies the
-    per-core stuck-at column masks to the resident store (one physical
-    crossbar per core — every tenant's rows on it share the core's faults).
-    Returns (val, idx) [N, B_l] or [N, B_l, M].
+    q_rx [N, n_core, B_l, d|W|k_max]; store [T, C_l, d|W]; rows [N] int32.
+    ``qmask`` [cores_per_shard] bool quarantines cores (True = excluded): a
+    quarantined core's candidates are masked BEFORE the core reduction
+    (distance -> d + 1 / similarity -> -2d), so a degraded receiver can never
+    win the vote for its own classes; all slots share the one physical link,
+    so one mask covers them all, and an all-False mask is value-identical to
+    qmask=None. ``stuck`` = (stuck0, stuck1) [cores_per_shard, W] packed
+    column masks, applied to the resident store after permuting
+    (`_apply_stuck` — one physical crossbar per core: every tenant's rows and
+    banks on it share the core's faults).
+    Returns (val, idx) — similarity value and GLOBAL class index of the
+    shard winner, [N, B_l] or [N, B_l, M].
     """
     t, c_l = store.shape[0], store.shape[1]
     last = store.shape[-1]
@@ -1103,20 +795,27 @@ def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
             )[:, :, 0, :]
     else:
         store_c = _apply_stuck(store_c, stuck, d, packed, 1)
-        if packed:
-            bank_rows = (
-                rows[:, None] * cores_per_shard + core_ids[None]
-            ).reshape(-1)
-            q_flat = q_rx.reshape(n * cores_per_shard, b_l, last)
-            if cfg.coarse_group:
-                dmin, amin = _coarse_fine_packed(
-                    cfg, store_c.reshape(t * cores_per_shard, c_core, last),
-                    q_flat, bank_rows=bank_rows,
+        # bank g = (slot, core) of the one launch -> store row rows[slot]
+        bank_rows = (
+            rows[:, None] * cores_per_shard + core_ids[None]
+        ).reshape(-1)
+        q_flat = q_rx.reshape(n * cores_per_shard, b_l, -1)
+        banks = store_c.reshape(t * cores_per_shard, c_core, last)
+        if packed or cfg.sparse:
+            if cfg.sparse:
+                # gather-overlap kernel on the raw index lists — integer- and
+                # tie-identical to hamming_topk_banked on the densified
+                # queries, so the packed downstream below is shared verbatim
+                dmin, amin = sparse_topk_banked(
+                    q_flat, jnp.take(banks, bank_rows, axis=0),
+                    use_kernel=cfg.use_kernels,
                 )  # each [N*n_core, B_l]
+            elif cfg.coarse_group:
+                dmin, amin = _coarse_fine_packed(cfg, banks, q_flat, bank_rows)
             else:
                 dmin, amin = hamming_topk_banked(
-                    q_flat, store_c.reshape(t * cores_per_shard, c_core, last),
-                    bank_rows=bank_rows, use_kernel=cfg.use_kernels,
+                    q_flat, banks, bank_rows=bank_rows,
+                    use_kernel=cfg.use_kernels,
                 )  # each [N*n_core, B_l]
             dmin = jnp.moveaxis(dmin.reshape(n, cores_per_shard, b_l), 2, 1)
             amin = jnp.moveaxis(amin.reshape(n, cores_per_shard, b_l), 2, 1)
@@ -1129,14 +828,7 @@ def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
             )[..., 0]
         else:
             if cfg.coarse_group:
-                core_rows = (
-                    rows[:, None] * cores_per_shard + core_ids[None]
-                ).reshape(-1)
-                vg, rg = _coarse_fine_unpacked(
-                    cfg, store_c.reshape(t * cores_per_shard, c_core, last),
-                    q_rx.reshape(n * cores_per_shard, b_l, last),
-                    bank_rows=core_rows,
-                )  # each [N*n_core, B_l]
+                vg, rg = _coarse_fine_unpacked(cfg, banks, q_flat, bank_rows)
                 val_c = jnp.moveaxis(vg.reshape(n, cores_per_shard, b_l), 2, 1)
                 idx_c = jnp.moveaxis(rg.reshape(n, cores_per_shard, b_l), 2, 1)
             else:
@@ -1159,50 +851,82 @@ def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
     return val, idx
 
 
-def make_mt_ota_serve(mesh: Mesh, cfg: ScaleOutConfig, process=None,
-                      faults=None) -> Callable:
-    """Build the multi-tenant slot-batched OTA serve step.
+def _build_ota_serve(mesh: Mesh, cfg: ScaleOutConfig, process=None,
+                     faults=None) -> Callable:
+    """The OTA serve step, slot-shaped and not yet jitted: the one builder
+    behind `make_mt_ota_serve` and its one-slot view `make_ota_serve`.
 
     fn(store [T, C, d|W], queries [N, B, S_tx, e_per, d|W], rows [N] i32,
        state phy.ChannelState, keys [N, 2] u32)
       -> (pred, maxsim), each [N, B] (baseline) or [N, B, M] (permuted).
 
-    One launch serves N resident slots against a T-tenant prototype store
-    (class axis sharded over ``model`` exactly like the standalone serve —
-    every tenant's bank lives on the same IMC cores); slot s searches tenant
-    bank ``rows[s]``. Onboarding/eviction edit the store outside this fn
-    (``dynamic_update_slice`` of one tenant row — no recompile here).
+    S_tx = model mesh size; e_per = ceil(m_tx / S_tx) encoders per column;
+    global encoder g = column * e_per + j; slots with g >= cfg.m_tx abstain.
+    One launch serves N slots against a T-tenant prototype store whose class
+    axis is sharded over ``model`` (every tenant's bank lives on the same
+    IMC cores); slot s searches tenant bank ``rows[s]`` with its own RNG
+    stream ``keys[s]``. Every stage is elementwise over the slots (see the
+    stage comment above), so slot s of the output is bit-identical to a
+    one-slot launch of slot s alone.
 
-    Per-slot prediction identity with `make_ota_serve`: the bundle collective
-    runs on the slot-flattened [N*B] rows through the SAME stage code
-    (elementwise over rows), the PHY fan-out vmaps over slots with slot s's
-    own key (vmapped counter-based RNG == the standalone draw for that key),
-    and the slot-batched search keeps standalone per-slot reduction order. So
-    row s of the output is bit-identical to a standalone serve of slot s's
-    queries against its tenant's codebook with key ``keys[s]`` — the lifecycle
-    tests pin this across representations and channels.
+    The OTA link is the pluggable PHY tier ``cfg.channel`` (`repro.phy`):
+    ``bsc`` (default) tallies the votes over the model axis (psum /
+    guard-bit psum_packed / rs_ag), then applies a per-core BSC at
+    ``state.ber``; ``ideal`` skips the noise; ``symbol`` replaces the
+    psum+BSC pair with the physical channel: ONE int32 psum of the
+    per-dimension TX bit-combo (== the constellation superposition, see
+    `phy.channel`), then per-core constellation lookup + AWGN +
+    decision-region decode from the same ChannelState the analytic BER came
+    from. ``state`` is sharded with the cores (`phy.state_spec`).
 
-    ``process`` switches to the living-channel form (see `make_ota_serve`):
+    With ``cfg.representation == "packed"`` store and queries are uint32
+    word arrays (see `hv.pack`); the bundled query, the per-core channel
+    noise, the store and the search all stay packed (the symbol tier decodes
+    bits, then packs), and the top-1 is ONE fused `hamming_topk_banked`
+    launch over every (slot, core[, permuted bank]) that reduces the class
+    axis in VMEM, so the [G, B, C] distance tensor never reaches HBM. The
+    vote tally itself shrinks with ``cfg.collective == "psum_packed"``
+    (guard-bit field packing sized by the ACTIVE voters, ONE uint32 psum,
+    bit-identical to the int8 psum). Predictions and maxsim are
+    bit-identical to the unpacked path on the same RNG stream
+    (cfg.noise="exact") across all collective modes.
 
-        fn(store, queries, rows, pstate, keys, process_key)
-          -> (pred, maxsim, pstate')
+    With ``cfg.representation == "sparse"`` queries are sorted int32 index
+    lists ([..., k_max], `core.sparse`): the OTA wire becomes
+    `collectives.sparse_index_allgather` + a local O(k log k) sparse
+    majority (``collective="index_ag"``; psum/psum_packed remain as dense
+    fallbacks for the crossover benchmark), the per-core BSC is the O(k)
+    drop+insert channel, and the top-1 is the gather-overlap
+    `sparse_topk_banked` kernel over the UNCHANGED packed store —
+    predictions are bit-identical to the packed serve at channel="ideal"
+    whenever no bundle saturates k_max.
 
-    ONE process step per serve step — every slot shares the one physical
-    link, evolved before the batched decode and searched under the shared
-    ``pstate.quarantine`` mask.
+    ``process`` (a `phy.ChannelProcess`) switches the serve to the LIVING
+    channel: ``state`` becomes a `phy.ProcessState` and a fixed process key
+    follows ``keys``. Each call first advances the channel ONE process step
+    (every slot shares the one physical link; the per-row RNG is
+    ``fold_in(fold_in(process_key, pstate.t), rx)`` — hold ``process_key``
+    FIXED across steps and the state sequence is reproducible from
+    `phy.rollout` on any mesh), then serves through the evolved
+    ``pstate.chan`` with ``pstate.quarantine`` masking quarantined cores out
+    of the top-1. With `phy.StaticProcess` predictions are bit-identical to
+    the process-free fn on the same keys.
 
-    ``faults`` threads a shared `faults.FaultState` exactly as in
-    `make_ota_serve` (one fault step per serve step — every slot rides the
-    same hardware): the fn appends ``(fstate, fault_key)`` inputs and a
-    ``fstate'`` output after the process arguments, and with the all-healthy
-    state stays bit-identical to the faults-free build.
+    ``faults`` (a `faults.FaultModel`) threads a `faults.FaultState` through
+    the step, ONE fault step per serve step: injected hard faults (dead
+    encoders/cores, stuck prototype cells, per-step vote erasures) plus the
+    tolerance machinery (live-voter re-bias, ``serve_rows`` failover,
+    ``rx_mask`` bank exclusion; see `repro.faults`). With
+    `faults.healthy_state` predictions are bit-identical to the faults-free
+    fn on the same keys — fault evolution consumes only ``fault_key``, never
+    the serve stream. In full, arguments and outputs in this order:
+
+        fn(store, queries, rows, state|pstate, keys[, pkey][, fstate, fkey])
+          -> (pred, maxsim[, pstate'][, fstate'])
+
+    The carried pytree structure is fixed, so an N-step serve loop compiles
+    ONCE. Sparse does not compose with ``process`` or ``faults``.
     """
-    if cfg.representation in ("sparse", "auto"):
-        raise ValueError(
-            "the multi-tenant serve does not support the sparse "
-            "representation (slot-batched bank indirection is a dense-store "
-            "contract); use representation='packed'"
-        )
     model_size = mesh.axis_sizes[mesh.axis_names.index("model")]
     assert cfg.n_rx_cores % model_size == 0, (cfg.n_rx_cores, model_size)
     cores_per_shard = cfg.n_rx_cores // model_size
@@ -1213,10 +937,16 @@ def make_mt_ota_serve(mesh: Mesh, cfg: ScaleOutConfig, process=None,
     chan = phy.get_channel(cfg.channel)
     _validate_channel(cfg, chan)
     _validate_coarse(cfg)
+    if cfg.sparse and (process is not None or faults is not None):
+        raise ValueError(
+            "representation='sparse' does not compose with living-channel "
+            "processes or fault injection (stuck-at / failover state is "
+            "word-addressed dense machinery); use representation='packed'"
+        )
 
-    def serve_core(store, queries, rows, state, keys, qmask, fstate=None):
-        # store: [T, C_l, d|W]; queries: [N, B_l, 1, e_per, d|W]; rows: [N];
-        # keys: [N, 2] — slot s serves with its request's own RNG stream
+    def serve_core(store, queries, rows, state, keys, qmask, fstate):
+        # store: [T, C_l, d|W]; queries: [N, B_l, 1, e_per, d|W|k_max];
+        # rows: [N]; keys: [N, 2] — slot s serves with its own RNG stream
         n, b_l = queries.shape[0], queries.shape[1]
         tx, gids, n_act_local = _tx_ids(cfg, e_per)
         q_mine = queries[:, :, 0]                   # [N, B_l, e_per, d|W]
@@ -1227,15 +957,23 @@ def make_mt_ota_serve(mesh: Mesh, cfg: ScaleOutConfig, process=None,
                 q_flat, gids
             )
         # --- ONE OTA collective for all slots: elementwise over the flattened
-        # [N*B] rows, so each row tallies exactly as its standalone serve ---
-        q_bundled = _ota_bundle(cfg, chan, model_size, e_per, q_flat, gids,
-                                n_act_local, fstate)
+        # [N*B] rows, so each row tallies exactly as it would alone ---
+        if cfg.sparse:
+            q_bundled = _sparse_bundle(cfg, chan, model_size, e_per, q_flat,
+                                       gids, n_act_local)
+        else:
+            q_bundled = _ota_bundle(cfg, chan, model_size, e_per, q_flat,
+                                    gids, n_act_local, fstate)
         q_bundled = q_bundled.reshape((n, b_l) + q_bundled.shape[1:])
         # --- PHY fan-out per slot with the slot's own key (RNG identity) ---
         dpos = _dpos(mesh, dp)
         kqs = jax.vmap(lambda k: jax.random.fold_in(k, dpos))(keys)
-        q_rx = _rx_fanout(cfg, chan, cores_per_shard, tx, q_bundled, state,
-                          kqs)  # [N, n_core, B_l, d|W]
+        if cfg.sparse:
+            q_rx = _sparse_rx_fanout(cfg, cores_per_shard, tx, q_bundled,
+                                     state, kqs)
+        else:
+            q_rx = _rx_fanout(cfg, chan, cores_per_shard, tx, q_bundled,
+                              state, kqs)  # [N, n_core, B_l, d|W]
         stuck = None
         if fstate is not None:
             q_rx, qmask = _apply_rx_faults(fstate, tx, cores_per_shard, q_rx,
@@ -1246,79 +984,42 @@ def make_mt_ota_serve(mesh: Mesh, cfg: ScaleOutConfig, process=None,
                                      rows, qmask, stuck)
         return _gather_top1(cfg, val, idx)
 
+    def body(store, queries, rows, state, keys, *extra):
+        # extra = ([pkey][, fstate, fkey]): step the process, then the
+        # faults, then serve through the state they leave
+        rx_base = jax.lax.axis_index("model") * cores_per_shard
+        qmask = fstate = None
+        evolved = ()
+        if process is not None:
+            pkey, *extra = extra
+            state = process.step(pkey, state, rx_base=rx_base)
+            evolved += (state,)
+            state, qmask = state.chan, state.quarantine
+        if faults is not None:
+            fstate, fkey = extra
+            fstate = faults.step(fkey, fstate, rx_base=rx_base)
+            evolved += (fstate,)
+        return serve_core(store, queries, rows, state, keys, qmask,
+                          fstate) + evolved
+
     dp_spec = dp if len(dp) > 1 else (dp[0] if dp else None)
-    if process is None and faults is None:
-        def body(store, queries, rows, state, keys):
-            return serve_core(store, queries, rows, state, keys, None)
-
-        in_specs = (
-            P(None, "model", None),                 # tenant store (class-sharded)
-            P(None, dp_spec, "model", None, None),  # per-slot encoder queries
-            P(),                                    # slot -> store row
-            phy.state_spec("model"),                # per-core channel state
-            P(),                                    # per-slot keys
-        )
-        out_specs = (P(None, dp_spec), P(None, dp_spec))
-    elif faults is None:
-        def body(store, queries, rows, pstate, keys, pkey):
-            tx = jax.lax.axis_index("model")
-            pstate = process.step(pkey, pstate, rx_base=tx * cores_per_shard)
-            pred, maxsim = serve_core(store, queries, rows, pstate.chan, keys,
-                                      pstate.quarantine)
-            return pred, maxsim, pstate
-
-        in_specs = (
-            P(None, "model", None),
-            P(None, dp_spec, "model", None, None),
-            P(),
-            phy.pstate_spec("model"),               # per-core process state
-            P(),                                    # per-slot keys
-            P(),                                    # process key (fixed)
-        )
-        out_specs = (P(None, dp_spec), P(None, dp_spec),
-                     phy.pstate_spec("model"))
-    elif process is None:
-        def body(store, queries, rows, state, keys, fstate, fkey):
-            tx = jax.lax.axis_index("model")
-            fstate = faults.step(fkey, fstate, rx_base=tx * cores_per_shard)
-            pred, maxsim = serve_core(store, queries, rows, state, keys, None,
-                                      fstate)
-            return pred, maxsim, fstate
-
-        in_specs = (
-            P(None, "model", None),
-            P(None, dp_spec, "model", None, None),
-            P(),
-            phy.state_spec("model"),
-            P(),                                    # per-slot keys
-            faultlib.fstate_spec("model"),          # per-core fault state
-            P(),                                    # fault key (fixed)
-        )
-        out_specs = (P(None, dp_spec), P(None, dp_spec),
-                     faultlib.fstate_spec("model"))
-    else:
-        def body(store, queries, rows, pstate, keys, pkey, fstate, fkey):
-            tx = jax.lax.axis_index("model")
-            pstate = process.step(pkey, pstate, rx_base=tx * cores_per_shard)
-            fstate = faults.step(fkey, fstate, rx_base=tx * cores_per_shard)
-            pred, maxsim = serve_core(store, queries, rows, pstate.chan, keys,
-                                      pstate.quarantine, fstate)
-            return pred, maxsim, pstate, fstate
-
-        in_specs = (
-            P(None, "model", None),
-            P(None, dp_spec, "model", None, None),
-            P(),
-            phy.pstate_spec("model"),
-            P(),                                    # per-slot keys
-            P(),                                    # process key (fixed)
-            faultlib.fstate_spec("model"),          # per-core fault state
-            P(),                                    # fault key (fixed)
-        )
-        out_specs = (P(None, dp_spec), P(None, dp_spec),
-                     phy.pstate_spec("model"), faultlib.fstate_spec("model"))
-
-    fn = compat.shard_map(
+    in_specs = (
+        P(None, "model", None),                 # tenant store (class-sharded)
+        P(None, dp_spec, "model", None, None),  # per-slot encoder queries
+        P(),                                    # slot -> store row
+        # per-core channel state, or the process state that carries it
+        phy.state_spec("model") if process is None else phy.pstate_spec("model"),
+        P(),                                    # per-slot keys
+    )
+    out_specs = (P(None, dp_spec), P(None, dp_spec))
+    if process is not None:
+        in_specs += (P(),)                      # process key (fixed)
+        out_specs += (phy.pstate_spec("model"),)
+    if faults is not None:
+        # per-core fault state, fault key (fixed)
+        in_specs += (faultlib.fstate_spec("model"), P())
+        out_specs += (faultlib.fstate_spec("model"),)
+    return compat.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
@@ -1326,7 +1027,55 @@ def make_mt_ota_serve(mesh: Mesh, cfg: ScaleOutConfig, process=None,
         axis_names=manual,
         check_vma=False,
     )
-    return jax.jit(fn)
+
+
+def make_ota_serve(
+    mesh: Mesh, cfg: ScaleOutConfig, process=None, faults=None
+) -> Callable[..., tuple[jax.Array, ...]]:
+    """Build the jitted OTA serve step of one batch: the one-slot view of
+    `_build_ota_serve` (a store of one bank, slot 0 searching row 0).
+
+    fn(protos [C, d|W], queries [B, S_tx, e_per, d|W|k_max], state, key
+       [, pkey][, fstate, fkey])
+      -> (pred, maxsim[, pstate'][, fstate']); pred [B] int32 (baseline) or
+         [B, m_tx] (permuted).
+
+    Serves every representation, "sparse" and "auto" too
+    (`resolve_representation`); the channel tiers, representations and the
+    ``process``/``faults`` forms are those of `_build_ota_serve`.
+    """
+    ring = _build_ota_serve(mesh, resolve_representation(cfg), process, faults)
+
+    def serve(protos, queries, state, key, *extra):
+        pred, maxsim, *evolved = ring(
+            protos[None], queries[None], jnp.zeros((1,), jnp.int32), state,
+            key[None], *extra)
+        return (pred[0], maxsim[0], *evolved)
+
+    return jax.jit(serve)
+
+
+def make_mt_ota_serve(mesh: Mesh, cfg: ScaleOutConfig, process=None,
+                      faults=None) -> Callable:
+    """Build the jitted multi-tenant slot-batched OTA serve step.
+
+    fn(store [T, C, d|W], queries [N, B, S_tx, e_per, d|W], rows [N] i32,
+       state phy.ChannelState, keys [N, 2] u32)
+      -> (pred, maxsim), each [N, B] (baseline) or [N, B, M] (permuted),
+
+    with the ``process``/``faults`` forms of `_build_ota_serve`. Slot s's
+    row of the output is bit-identical to `make_ota_serve` of slot s's
+    queries against its tenant's codebook with key ``keys[s]``.
+    Onboarding/eviction edit the store outside this fn
+    (``dynamic_update_slice`` of one tenant row — no recompile here).
+    """
+    if cfg.representation in ("sparse", "auto"):
+        raise ValueError(
+            "the multi-tenant serve does not support the sparse "
+            "representation (slot-batched bank indirection is a dense-store "
+            "contract); use representation='packed'"
+        )
+    return jax.jit(_build_ota_serve(mesh, cfg, process, faults))
 
 
 def make_wired_serve(

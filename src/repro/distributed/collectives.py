@@ -154,7 +154,8 @@ def packed_vote_allreduce(
     construction) while sending ``ceil(d/k)`` uint32 lanes instead of d int8
     bytes — a 2x wire-byte cut at the paper's M=3 operating point on a 4-wide
     model axis (4-bit fields, k=8). This is the OTA majority collective of
-    `make_ota_serve(collective="psum_packed")`.
+    the OTA serve builder at ``collective="psum_packed"``
+    (`make_mt_ota_serve` and its one-slot view `make_ota_serve`).
 
     **Active-slot-aware mode** (`n_active` + `local_active`): when only
     `n_active` voters across the whole group are live (every other slot votes

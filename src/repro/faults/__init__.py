@@ -3,10 +3,11 @@
 See `repro.faults.model` for the `FaultState` pytree (memory / node / wire
 fault surfaces), the evolution-law registry (`FAULTS`, mirroring
 `phy.PROCESSES`), the host-side failover planner, and the combo-wire erasure
-helpers. `core.scaleout.make_ota_serve` / `make_mt_ota_serve` thread the
-state through the serve step when built with a ``faults=`` model; the
-serving layer's `FaultController` promotes persistently-dead rows from PHY
-quarantine to a failover remap at the step barrier.
+helpers. The OTA serve builder (`core.scaleout.make_mt_ota_serve` and its
+one-slot view `make_ota_serve`) threads the state through the serve step
+when built with a ``faults=`` model; the serving layer's `FaultController`
+promotes persistently-dead rows from PHY quarantine to a failover remap at
+the step barrier.
 """
 from repro.faults.model import (
     FAULTS,

@@ -9,9 +9,9 @@ and encoder votes erased from the over-the-air superposition. At WHYPE scale
 ignores them silently misclassifies every query whose class lives on a dead
 core.
 
-Everything rides in ONE `FaultState` pytree threaded through both serve
-steps (`core.scaleout.make_ota_serve` / `make_mt_ota_serve` with a
-``faults=`` model), split into three fault surfaces:
+Everything rides in ONE `FaultState` pytree threaded through the one OTA
+serve builder (`core.scaleout.make_mt_ota_serve` and its one-slot view
+`make_ota_serve`, with a ``faults=`` model), split into three fault surfaces:
 
 * **wire faults** — ``dead_tx`` (permanent) and ``vote_drop`` (per-step,
   refreshed by the fault process) erase encoder slots from the bundle. On the

@@ -22,7 +22,8 @@ one `Channel` interface:
 The precharacterization outputs travel as a :class:`ChannelState` pytree
 (channel matrix ``h``, chosen ``phase_idx``, constellation ``symbols``,
 decision centroids ``c0``/``c1``, noise density ``n0``, per-RX ``ber`` +
-``valid``) threaded through ``make_ota_serve``/``make_wired_serve`` in place
+``valid``) threaded through the OTA serve builder (``make_mt_ota_serve`` and
+its one-slot view ``make_ota_serve``) and ``make_wired_serve`` in place
 of the bare BER array; every leaf with a leading RX axis shards over the
 ``model`` mesh axis exactly like the prototype memory it sits next to.
 

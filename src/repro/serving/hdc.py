@@ -7,10 +7,10 @@ batching machinery that fronts the LMs (``repro.serving.slotring`` /
 
 * ``TenantRegistry`` — many classifier *tenants* resident at once. Every
   tenant's prototype bank occupies one row of ONE banked store
-  [max_tenants, C, d|W] whose class axis is sharded over ``model`` exactly
-  like the standalone serve (each tenant's classes live on the same IMC
-  cores). Onboarding/eviction is a jitted ``dynamic_update_slice`` of one
-  tenant row — no recompile, the serve step never changes shape.
+  [max_tenants, C, d|W] whose class axis is sharded over ``model`` (each
+  tenant's classes live on the same IMC cores). Onboarding/eviction is a
+  jitted ``dynamic_update_slice`` of one tenant row — no recompile, the
+  serve step never changes shape.
 * ``HDCEngine`` — a ``SlotRingEngine`` whose state is per-slot query batches +
   tenant store-rows + RNG keys, and whose step is ONE
   ``scaleout.make_mt_ota_serve`` launch: the full wire path (OTA vote
@@ -23,11 +23,12 @@ batching machinery that fronts the LMs (``repro.serving.slotring`` /
   tenant, admission swaps the query batch into a free slot, and every running
   slot finishes at each step barrier.
 
-Per-slot results are bit-identical to a standalone ``make_ota_serve`` of that
-request against its tenant's codebook with the request's own key (see
-`make_mt_ota_serve`), so multi-tenant batching is purely a throughput/latency
-optimization — pinned by tests/test_serving_hdc.py across representations and
-channels.
+``make_mt_ota_serve`` and ``make_ota_serve`` are two views of one serve
+builder (`scaleout._build_ota_serve`): the ring step, and its one-slot view.
+Per-slot results are bit-identical to ``make_ota_serve`` of that request
+against its tenant's codebook with the request's own key, so multi-tenant
+batching is purely a throughput/latency optimization — pinned by
+tests/test_serving_hdc.py across representations and channels.
 """
 from __future__ import annotations
 
@@ -125,11 +126,12 @@ class TenantRegistry:
     """Resident per-tenant prototype banks in one class-sharded store.
 
     ``store`` is [max_tenants, n_classes, d|W] with the class axis sharded
-    over ``model`` (the same placement a standalone serve gives one tenant's
-    codebook). ``onboard``/``evict`` edit one row via a single jitted
-    ``dynamic_update_slice`` (row index traced — one compiled program for the
-    registry's lifetime); evicted rows keep their stale contents, which is
-    safe because no slot maps to them until re-onboarding overwrites the row.
+    over ``model`` (the same placement the one-slot ``make_ota_serve`` gives
+    one tenant's codebook). ``onboard``/``evict`` edit one row via a single
+    jitted ``dynamic_update_slice`` (row index traced — one compiled program
+    for the registry's lifetime); evicted rows keep their stale contents,
+    which is safe because no slot maps to them until re-onboarding overwrites
+    the row.
     """
 
     def __init__(self, mesh: Mesh, cfg: ScaleOutConfig, max_tenants: int):
@@ -626,7 +628,7 @@ class HDCScheduler(SlotScheduler):
     launch, not a token loop), so continuous batching here means: free slots
     refill from the age-ordered queue every step, and a step serves however
     many tenants are resident — the single-launch amortization the benchmark
-    measures against per-request standalone serves.
+    measures against per-request one-slot serves.
     """
 
     def __init__(self, engine: HDCEngine,

@@ -1,8 +1,8 @@
 """HDC-as-a-service: multi-tenant slot-batched serving must be bit-identical
-per slot to standalone `make_ota_serve` (same RNG stream), tenant lifecycle
-(admit -> serve -> evict -> re-admit) must be prediction-identical to a fresh
-standalone serve across representations and channels, and the scheduler must
-drain with ceil(R / slots) steps."""
+per slot to its one-slot view `make_ota_serve` (same RNG stream), tenant
+lifecycle (admit -> serve -> evict -> re-admit) must be prediction-identical to
+a fresh one-slot serve across representations and channels, and the scheduler
+must drain with ceil(R / slots) steps."""
 import dataclasses
 import os
 import subprocess
@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from conftest import make_test_mesh
-from repro import phy
+from repro import faults as faultlib, phy
 from repro.core import classifier, hypervector as hv, scaleout
 from repro.serving import HDCEngine, HDCScheduler
 
@@ -38,39 +38,61 @@ def _tenant_protos(cfg, book):
     return hv.pack(book) if cfg.packed else book
 
 
-def test_mt_serve_bit_identical_per_slot():
-    """Each slot of one multi-tenant launch == the standalone serve of that
-    slot's queries against its tenant's codebook with the slot's own key —
-    including slots sharing a tenant and nonzero per-core BER."""
+@pytest.mark.parametrize("form", ["plain", "process", "faults",
+                                  "process+faults"])
+def test_mt_serve_bit_identical_per_slot(form):
+    """Each slot of one multi-tenant launch == the one-slot view
+    (`make_ota_serve`) of that slot's queries against its tenant's codebook
+    with the slot's own key — including slots sharing a tenant and nonzero
+    per-core BER — in every form the one serve builder assembles: plain,
+    with a channel process, with faults, and with both (whose evolved states
+    must agree too)."""
     mesh = make_test_mesh((1, 1), ("data", "model"))
+    kw = {}
+    if "process" in form:
+        kw["process"] = phy.StaticProcess()
+    if "faults" in form:
+        kw["faults"] = faultlib.StaticFaults()
     for rep in ("unpacked", "packed"):
         for permuted in (False, True):
             cfg = _cfg(permuted=permuted, representation=rep)
             books = _books(cfg, 3)
             state = phy.state_from_ber(jnp.full((cfg.n_rx_cores,), 0.05), cfg.m_tx)
-            serve = scaleout.make_ota_serve(mesh, cfg)
-            mt = scaleout.make_mt_ota_serve(mesh, cfg)
+            extra = ()
+            if "process" in kw:
+                state = kw["process"].init(state)
+                extra += (jax.random.PRNGKey(7),)
+            if "faults" in kw:
+                extra += (faultlib.healthy_for(cfg, 1), jax.random.PRNGKey(9))
+            serve = scaleout.make_ota_serve(mesh, cfg, **kw)
+            mt = scaleout.make_mt_ota_serve(mesh, cfg, **kw)
             rows = jnp.array([2, 0, 2], jnp.int32)  # slots 0 and 2 share tenant 2
             keys = jnp.stack([jax.random.PRNGKey(100 + s) for s in range(3)])
             store = jnp.stack([_tenant_protos(cfg, b) for b in books])
-            qs, want_p, want_s = [], [], []
+            qs, want_p, want_s, want_ev = [], [], [], []
             for s in range(3):
                 book = books[int(rows[s])]
                 _, q = scaleout.make_queries(jax.random.PRNGKey(50 + s), cfg, book, 1)
                 qs.append(q)
-                pr, si = serve(_tenant_protos(cfg, book), q, state, keys[s])
+                pr, si, *ev = serve(_tenant_protos(cfg, book), q, state,
+                                    keys[s], *extra)
                 want_p.append(np.asarray(pr))
                 want_s.append(np.asarray(si))
-            pred, sim = mt(store, jnp.stack(qs), rows, state, keys)
+                want_ev.append(ev)
+            pred, sim, *got_ev = mt(store, jnp.stack(qs), rows, state, keys,
+                                    *extra)
             np.testing.assert_array_equal(np.asarray(pred), np.stack(want_p))
             np.testing.assert_array_equal(np.asarray(sim), np.stack(want_s))
+            assert len(got_ev) == len(kw)
+            for ev in want_ev:
+                jax.tree.map(np.testing.assert_array_equal, got_ev, ev)
 
 
 @pytest.mark.parametrize("rep", ["unpacked", "packed"])
 @pytest.mark.parametrize("channel", ["bsc", "symbol"])
 def test_tenant_lifecycle_identity(rep, channel):
     """admit -> serve -> evict -> re-admit (lands on a DIFFERENT store row)
-    stays prediction-identical to a fresh standalone serve, for every
+    stays prediction-identical to a fresh one-slot serve, for every
     representation x channel tier."""
     cfg = _cfg(representation=rep, channel=channel)
     mesh = make_test_mesh((1, 1), ("data", "model"))
@@ -142,7 +164,7 @@ def test_scheduler_interleaves_tenants_and_drains():
 def test_mt_serve_multidevice_packed_collectives():
     """On a real 2x4 mesh the slot-flattened wire path (guard-bit packed vote
     all-reduce, packed reduce-scatter + all-gather) must stay bit-identical
-    per slot to the standalone serve — the collectives see [N*B] rows."""
+    per slot to the one-slot serve — the collectives see [N*B] rows."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC
