@@ -537,8 +537,8 @@ def _apply_rx_faults(fstate, tx, cores_per_shard: int, q_rx, qmask,
     A dead core's received copy is zeroed (it answers nothing), then bank i's
     search query is gathered from physical core ``serve_rows[i]`` (global ids,
     same-shard by the `faults.plan_failover` contract; identity = no remap) —
-    the query-side dual of the ``bank_rows`` prototype indirection, equally
-    recompile-free. ``rx_mask`` joins the PHY quarantine mask so banks with
+    the query-side dual of the slots' tenant-row take in `_shard_top1_slots`,
+    equally recompile-free. ``rx_mask`` joins the PHY quarantine mask so banks with
     no healthy server can never win the top-1. All-healthy state: zero mask,
     identity gather, all-False qmask — value-identical to no faults at all.
     """
@@ -553,7 +553,7 @@ def _apply_rx_faults(fstate, tx, cores_per_shard: int, q_rx, qmask,
 
 
 def _group_summaries(cfg: ScaleOutConfig, banks: jax.Array) -> jax.Array:
-    """Per-bank coarse summaries: banks [T, C_core, d|W] -> [T, n_grp, d|W].
+    """Per-bank coarse summaries: banks [G, C_core, d|W] -> [G, n_grp, d|W].
 
     Each contiguous `coarse_group`-row block collapses to its strict-majority
     bundle — the block's centroid in Hamming space. Computed in-graph from the
@@ -561,52 +561,47 @@ def _group_summaries(cfg: ScaleOutConfig, banks: jax.Array) -> jax.Array:
     always sees what the exact scan sees.
     """
     gs = cfg.coarse_group
-    t, c_core, last = banks.shape
-    grp = banks.reshape(t, c_core // gs, gs, last)
-    members = jnp.moveaxis(grp, 2, 0)                 # [gs, T, n_grp, last]
+    g, c_core, last = banks.shape
+    grp = banks.reshape(g, c_core // gs, gs, last)
+    members = jnp.moveaxis(grp, 2, 0)                 # [gs, G, n_grp, last]
     return hv.majority_packed(members) if cfg.packed else hv.majority(members)
 
 
-def _coarse_fine_packed(cfg: ScaleOutConfig, banks, q, bank_rows):
+def _coarse_fine_packed(cfg: ScaleOutConfig, banks, q):
     """Two-level packed search: coarse top-keep screen over the group
     summaries (ONE fused top-k launch), exact rescore over only the
-    surviving rows. banks [T, C_core, W], q [G, B, W], bank_rows [G] (query
-    bank g searches table row ``bank_rows[g]``) -> (dist, row) of each
-    bank's winner, both [G, B] int32.
+    surviving rows. banks [G, C_core, W], q [G, B, W] (query bank g searches
+    bank g) -> (dist, row) of each bank's winner, both [G, B] int32.
 
     Survivor groups are re-sorted ascending and the rescore minimizes ONE
     ``dist*c_core + row`` int32 key, so ties break toward the lowest class
     row exactly like the flat scan — predictions match the flat path whenever
     the screen recalls the true winner, and keep == n_grp is bit-identical.
-    The survivor rows are gathered straight from the bank table (advanced
-    indexing), so the expanded [G, C_core, W] view never materializes — the
-    same indirection contract as `hamming_topk_banked`.
     """
     gs = cfg.coarse_group
-    t, c_core, w = banks.shape
-    g, b_l = q.shape[0], q.shape[1]
+    g, c_core = banks.shape[:2]
+    b_l = q.shape[1]
     n_grp = c_core // gs
     keep = min(cfg.coarse_keep, n_grp)
-    summ = _group_summaries(cfg, banks)               # [T, n_grp, W]
+    summ = _group_summaries(cfg, banks)               # [G, n_grp, W]
     _, gidx = hamming_topk_banked(
-        q, summ, k=keep, bank_rows=bank_rows, use_kernel=cfg.use_kernels
+        q, summ, k=keep, use_kernel=cfg.use_kernels
     )                                                 # [G, B, keep]
     gidx = jnp.sort(gidx, axis=-1)
     rows = (
         gidx[..., None] * gs + jnp.arange(gs, dtype=jnp.int32)
     ).reshape(g, b_l, keep * gs)
-    cand = banks[bank_rows[:, None, None], rows]      # [G, B, keep*gs, W]
+    cand = banks[jnp.arange(g)[:, None, None], rows]  # [G, B, keep*gs, W]
     x = jnp.bitwise_xor(q[:, :, None, :], cand)
     dist = jnp.sum(jax.lax.population_count(x).astype(jnp.int32), axis=-1)
     key = jnp.min(dist * c_core + rows, axis=-1)      # single-key first-min
     return key // c_core, key % c_core
 
 
-def _coarse_fine_unpacked(cfg: ScaleOutConfig, banks, q, bank_rows):
+def _coarse_fine_unpacked(cfg: ScaleOutConfig, banks, q):
     """Unpacked (fp32 bipolar MXU) coarse-to-fine: screen via the summary
-    dots, rescore only the surviving rows. banks [T, C_core, d] uint8,
-    q [G, B, d], bank_rows [G] -> (val f32, row i32) of each bank's winner,
-    both [G, B].
+    dots, rescore only the surviving rows. banks [G, C_core, d] uint8,
+    q [G, B, d] -> (val f32, row i32) of each bank's winner, both [G, B].
 
     `lax.top_k` is stable (ties keep the lower group) and survivors are
     rescored in ascending row order through the same integer-valued fp32
@@ -615,19 +610,19 @@ def _coarse_fine_unpacked(cfg: ScaleOutConfig, banks, q, bank_rows):
     keep == n_grp is bit-identical.
     """
     gs = cfg.coarse_group
-    t, c_core, d = banks.shape
-    g, b_l = q.shape[0], q.shape[1]
+    g, c_core = banks.shape[:2]
+    b_l = q.shape[1]
     n_grp = c_core // gs
     keep = min(cfg.coarse_keep, n_grp)
-    summ = _group_summaries(cfg, banks)               # [T, n_grp, d]
+    summ = _group_summaries(cfg, banks)               # [G, n_grp, d]
     csims = jax.vmap(
         lambda qc, sc: _local_search(qc, sc, cfg.use_kernels)
-    )(q, jnp.take(summ, bank_rows, axis=0))           # [G, B, n_grp]
+    )(q, summ)                                        # [G, B, n_grp]
     gidx = jnp.sort(jax.lax.top_k(csims, keep)[1].astype(jnp.int32), axis=-1)
     rows = (
         gidx[..., None] * gs + jnp.arange(gs, dtype=jnp.int32)
     ).reshape(g, b_l, keep * gs)
-    cand = banks[bank_rows[:, None, None], rows]      # [G, B, keep*gs, d]
+    cand = banks[jnp.arange(g)[:, None, None], rows]  # [G, B, keep*gs, d]
     qb = 2.0 * q.astype(jnp.float32) - 1.0
     cb = 2.0 * cand.astype(jnp.float32) - 1.0
     sims = jnp.einsum("gbd,gbrd->gbr", qb, cb)        # integer-valued f32
@@ -707,14 +702,16 @@ def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
                       q_rx, store, rows, qmask=None, stuck=None):
     """This shard's local top-1, slot-batched: slot s searches tenant bank
     ``rows[s]`` of the resident store, each core its class sub-shard (with
-    the M permuted banks when cfg.permuted). ONE `hamming_topk_banked`
-    launch covers every (slot, core[, permuted bank]) — the G axis of the
-    kernel grid — via the ``bank_rows`` indirection (packed), a row gather
-    (unpacked MXU path) or the slots' banks taken from the store (sparse:
-    `sparse_topk_banked` has no indirection); never a vmap over the kernel
-    (its revisited-tile running-min is not vmap-safe). Each slot is reduced
-    in [B, core(, M), class] axis order, so its ties break as they would in
-    a one-slot search.
+    the M permuted banks when cfg.permuted). The slots' tenant rows are taken
+    from the store FIRST (rows may repeat: two slots on one tenant get two
+    copies), so every per-step transform — the split into cores, the stuck
+    masks, the permutes, the coarse summaries — runs on the N rows searched
+    and never on all T tenants held. ONE `hamming_topk_banked` launch then
+    covers every (slot, core[, permuted bank]) in that order — the G axis of
+    the kernel grid; never a vmap over the kernel (its revisited-tile
+    running-min is not vmap-safe). Each slot is reduced in
+    [B, core(, M), class] axis order, so its ties break as they would in a
+    one-slot search.
 
     q_rx [N, n_core, B_l, d|W|k_max]; store [T, C_l, d|W]; rows [N] int32.
     ``qmask`` [cores_per_shard] bool quarantines cores (True = excluded): a
@@ -723,41 +720,39 @@ def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
     win the vote for its own classes; all slots share the one physical link,
     so one mask covers them all, and an all-False mask is value-identical to
     qmask=None. ``stuck`` = (stuck0, stuck1) [cores_per_shard, W] packed
-    column masks, applied to the resident store after permuting
+    column masks, applied to the slots' rows after permuting
     (`_apply_stuck` — one physical crossbar per core: every tenant's rows and
     banks on it share the core's faults).
     Returns (val, idx) — similarity value and GLOBAL class index of the
     shard winner, [N, B_l] or [N, B_l, M].
     """
-    t, c_l = store.shape[0], store.shape[1]
-    last = store.shape[-1]
+    c_l, last = store.shape[1], store.shape[-1]
     d = cfg.dim
     n, b_l = q_rx.shape[0], q_rx.shape[2]
     packed = cfg.packed
     assert c_l % cores_per_shard == 0
     c_core = c_l // cores_per_shard
-    core_ids = jnp.arange(cores_per_shard)
-    store_c = store.reshape(t, cores_per_shard, c_core, last)
+    # one dynamic slice per slot, not a gather: the TPU compiler lowers a
+    # gather of whole tenants by first slicing EVERY tenant into class
+    # chunks, a pass over the whole store; a slice reads only its tenant
+    slots_c = jnp.stack([
+        jax.lax.dynamic_index_in_dim(store, rows[s], 0, keepdims=False)
+        for s in range(n)
+    ]).reshape(n, cores_per_shard, c_core, last)     # [N, n_core, c_core, .]
 
     if cfg.permuted:
+        rho = hv.permute_packed if packed else hv.permute
+        banks = jnp.stack([rho(slots_c, m) for m in range(cfg.m_tx)], 2)
+        banks = _apply_stuck(banks, stuck, d, packed, 1)
+        # banks [N, n_core, M, c_core, .]: bank g of the launch is (slot, core, m)
         if packed:
-            # permute the T-tenant store ONCE (not per slot); bank g of the
-            # single launch is (slot, core, m) -> store row rows[slot]
-            banks = jnp.stack(
-                [hv.permute_packed(store_c, m) for m in range(cfg.m_tx)], 2
-            )  # [T, n_core, M, c_core, W]
-            banks = _apply_stuck(banks, stuck, d, True, 1)
-            bank_rows = (
-                (rows[:, None] * cores_per_shard + core_ids[None])[:, :, None]
-                * cfg.m_tx + jnp.arange(cfg.m_tx)[None, None]
-            ).reshape(-1)
             g = n * cores_per_shard * cfg.m_tx
             q_rep = jnp.broadcast_to(
                 q_rx[:, :, None], (n, cores_per_shard, cfg.m_tx) + q_rx.shape[2:]
             ).reshape(g, b_l, last)
             dmin, amin = hamming_topk_banked(
-                q_rep, banks.reshape(t * cores_per_shard * cfg.m_tx, c_core, last),
-                bank_rows=bank_rows, use_kernel=cfg.use_kernels,
+                q_rep, banks.reshape(g, c_core, last),
+                use_kernel=cfg.use_kernels,
             )  # each [g, B_l]
             dmin = jnp.moveaxis(
                 dmin.reshape(n, cores_per_shard, cfg.m_tx, b_l), 3, 1
@@ -773,16 +768,11 @@ def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
                 amin, core_star[:, :, None, :], 2
             )[:, :, 0, :]
         else:
-            banks = jnp.stack(
-                [hv.permute(store_c, m) for m in range(cfg.m_tx)], 2
-            )  # [T, n_core, M, c_core, d]
-            banks = _apply_stuck(banks, stuck, d, False, 1)
-            banks_n = jnp.take(banks, rows, axis=0)  # [N, n_core, M, c_core, d]
             sims = jax.vmap(jax.vmap(
                 lambda qc, pc: jax.vmap(
                     lambda bank: _local_search(qc, bank, cfg.use_kernels)
                 )(pc)
-            ))(q_rx, banks_n)  # [N, n_core, M, B_l, c_core]
+            ))(q_rx, banks)  # [N, n_core, M, B_l, c_core]
             sims = jnp.moveaxis(sims, 3, 1)  # [N, B_l, n_core, M, c_core]
             val_c = jnp.max(sims, -1)
             idx_c = jnp.argmax(sims, -1).astype(jnp.int32)
@@ -794,28 +784,23 @@ def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
                 idx_c, core_star[:, :, None, :], 2
             )[:, :, 0, :]
     else:
-        store_c = _apply_stuck(store_c, stuck, d, packed, 1)
-        # bank g = (slot, core) of the one launch -> store row rows[slot]
-        bank_rows = (
-            rows[:, None] * cores_per_shard + core_ids[None]
-        ).reshape(-1)
+        slots_c = _apply_stuck(slots_c, stuck, d, packed, 1)
+        # bank g = (slot, core) of the one launch
         q_flat = q_rx.reshape(n * cores_per_shard, b_l, -1)
-        banks = store_c.reshape(t * cores_per_shard, c_core, last)
+        banks = slots_c.reshape(n * cores_per_shard, c_core, last)
         if packed or cfg.sparse:
             if cfg.sparse:
                 # gather-overlap kernel on the raw index lists — integer- and
                 # tie-identical to hamming_topk_banked on the densified
                 # queries, so the packed downstream below is shared verbatim
                 dmin, amin = sparse_topk_banked(
-                    q_flat, jnp.take(banks, bank_rows, axis=0),
-                    use_kernel=cfg.use_kernels,
+                    q_flat, banks, use_kernel=cfg.use_kernels,
                 )  # each [N*n_core, B_l]
             elif cfg.coarse_group:
-                dmin, amin = _coarse_fine_packed(cfg, banks, q_flat, bank_rows)
+                dmin, amin = _coarse_fine_packed(cfg, banks, q_flat)
             else:
                 dmin, amin = hamming_topk_banked(
-                    q_flat, banks, bank_rows=bank_rows,
-                    use_kernel=cfg.use_kernels,
+                    q_flat, banks, use_kernel=cfg.use_kernels,
                 )  # each [N*n_core, B_l]
             dmin = jnp.moveaxis(dmin.reshape(n, cores_per_shard, b_l), 2, 1)
             amin = jnp.moveaxis(amin.reshape(n, cores_per_shard, b_l), 2, 1)
@@ -828,15 +813,13 @@ def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
             )[..., 0]
         else:
             if cfg.coarse_group:
-                vg, rg = _coarse_fine_unpacked(cfg, banks, q_flat, bank_rows)
+                vg, rg = _coarse_fine_unpacked(cfg, banks, q_flat)
                 val_c = jnp.moveaxis(vg.reshape(n, cores_per_shard, b_l), 2, 1)
                 idx_c = jnp.moveaxis(rg.reshape(n, cores_per_shard, b_l), 2, 1)
             else:
-                protos_n = jnp.take(store_c, rows, axis=0)
-                # protos_n: [N, n_core, c_core, d]
                 sims = jax.vmap(jax.vmap(
                     lambda qc, pc: _local_search(qc, pc, cfg.use_kernels)
-                ))(q_rx, protos_n)  # [N, n_core, B_l, c_core]
+                ))(q_rx, slots_c)  # [N, n_core, B_l, c_core]
                 sims = jnp.moveaxis(sims, 2, 1)  # [N, B_l, n_core, c_core]
                 val_c = jnp.max(sims, -1)
                 idx_c = jnp.argmax(sims, -1).astype(jnp.int32)
@@ -849,7 +832,6 @@ def _shard_top1_slots(cfg: ScaleOutConfig, cores_per_shard: int, tx,
             )[..., 0]
     idx = (tx * c_l + core_star * c_core + idx_in_core).astype(jnp.int32)
     return val, idx
-
 
 def _build_ota_serve(mesh: Mesh, cfg: ScaleOutConfig, process=None,
                      faults=None) -> Callable:

@@ -16,7 +16,8 @@ batching machinery that fronts the LMs (``repro.serving.slotring`` /
   ``scaleout.make_mt_ota_serve`` launch: the full wire path (OTA vote
   collective, guard-bit packing, pluggable PHY channel) runs slot-batched,
   and the per-core search is a single ``hamming_topk_banked`` call whose bank
-  axis spans (slot, core[, permuted bank]) via the ``bank_rows`` indirection.
+  axis spans (slot, core[, permuted bank]) of the slots' tenant rows, taken
+  from the store before anything else touches it.
   Unlike LM decode, every slot COMPLETES each step — admission latency is the
   only queueing — so the emission is the (pred, maxsim) pair itself.
 * ``HDCScheduler`` — the ``SlotScheduler`` specialization: requests name a

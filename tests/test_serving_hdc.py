@@ -16,7 +16,7 @@ import pytest
 
 from conftest import make_test_mesh
 from repro import faults as faultlib, phy
-from repro.core import classifier, hypervector as hv, scaleout
+from repro.core import classifier, hypervector as hv, scaleout, sparse
 from repro.serving import HDCEngine, HDCScheduler
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -86,6 +86,98 @@ def test_mt_serve_bit_identical_per_slot(form):
             assert len(got_ev) == len(kw)
             for ev in want_ev:
                 jax.tree.map(np.testing.assert_array_equal, got_ev, ev)
+
+
+def _whole_store_slot_search(cfg, q_rx, store, rows, qmask, stuck):
+    """The whole-store formulation of a shard's slot search: every tenant's
+    banks split into cores (and permuted), the stuck masks applied to all of
+    them, bank g = (slot, core[, m]) searched through `hamming_topk_banked`'s
+    ``bank_rows`` indirection into the [T*n_core(*M), c_core, W] table, then
+    reduced over cores (first minimum). Packed operands; returns (val, idx),
+    [N, B] or [N, B, M]."""
+    from repro.kernels.hamming import hamming_topk_banked
+
+    t, c_l, w = store.shape
+    n, n_core, b = q_rx.shape[:3]
+    c_core = c_l // n_core
+    m = cfg.m_tx if cfg.permuted else 1
+    banks = store.reshape(t, n_core, 1, c_core, w)
+    if cfg.permuted:
+        banks = jnp.concatenate(
+            [hv.permute_packed(banks, j) for j in range(m)], 2)
+    if stuck is not None:
+        s0, s1 = (s[None, :, None, None] for s in stuck)
+        banks = (banks & ~s0) | s1
+    bank_rows = ((rows[:, None, None] * n_core
+                  + jnp.arange(n_core)[None, :, None]) * m
+                 + jnp.arange(m)).reshape(-1)
+    q_rep = jnp.broadcast_to(q_rx[:, :, None], (n, n_core, m, b, w))
+    dmin, amin = hamming_topk_banked(
+        q_rep.reshape(-1, b, w), banks.reshape(t * n_core * m, c_core, w),
+        bank_rows=bank_rows, use_kernel=False)
+    dmin = dmin.reshape(n, n_core, m, b)
+    amin = amin.reshape(n, n_core, m, b)
+    if qmask is not None:
+        dmin = jnp.where(qmask[None, :, None, None], cfg.dim + 1, dmin)
+    core = jnp.argmin(dmin, 1)                            # [N, M, B]
+    idx = core * c_core + jnp.take_along_axis(amin, core[:, None], 1)[:, 0]
+    val = cfg.dim - 2 * jnp.min(dmin, 1)
+    val, idx = jnp.moveaxis(val, 1, 2), jnp.moveaxis(idx, 1, 2)  # [N, B, M]
+    return (val, idx) if cfg.permuted else (val[..., 0], idx[..., 0])
+
+
+# the sparse serve composes with no fault injection: no stuck case there
+@pytest.mark.parametrize("form,case", [
+    (form, case)
+    for form in ("packed", "packed-kernel", "permuted", "unpacked",
+                 "unpacked-permuted", "sparse", "coarse", "unpacked-coarse")
+    for case in ("repeated", "descending", "more_slots_than_tenants", "stuck")
+    if (form, case) != ("sparse", "stuck")
+])
+def test_slot_search_equals_the_whole_store_search(form, case):
+    """The shard's slot search takes the slots' tenant rows before it splits,
+    masks, permutes or summarizes them; its (val, idx) equal, trial by trial,
+    the whole-store formulation that transforms all T tenants and indexes
+    the searched banks through ``bank_rows`` — with repeated and descending
+    rows, more slots than tenants, stuck-at masks with a quarantined core,
+    every representation, the permuted banks and the coarse screen (its
+    keep == group count is the flat scan)."""
+    rep = {"unpacked": "unpacked", "unpacked-permuted": "unpacked",
+           "unpacked-coarse": "unpacked", "sparse": "sparse"}.get(form,
+                                                                  "packed")
+    cfg = _cfg(representation=rep, permuted="permuted" in form,
+               use_kernels=form == "packed-kernel",
+               coarse_group=5 if "coarse" in form else 0, coarse_keep=2,
+               k_max=48 if rep == "sparse" else 0)
+    n_core, b, d = cfg.n_rx_cores, 8, cfg.dim
+    rows = jnp.array({"repeated": [1, 1, 0], "descending": [2, 1, 0],
+                      "more_slots_than_tenants": [0, 2, 1, 1, 0],
+                      "stuck": [2, 0, 2]}[case], jnp.int32)
+    n, t = rows.shape[0], 3
+    k_store, k_q, k_s = jax.random.split(jax.random.PRNGKey(23), 3)
+    store = hv.pack(jax.random.bernoulli(
+        k_store, 0.5, (t, cfg.n_classes, d)).astype(jnp.uint8))
+    # sparse queries carry at most k_max set bits; dense ones are balanced
+    bits = jax.random.bernoulli(k_q, 1 / 32 if rep == "sparse" else 0.5,
+                                (n, n_core, b, d)).astype(jnp.uint8)
+    qmask = stuck = None
+    if case == "stuck":
+        s0, s1 = jax.random.bits(k_s, (2, n_core, d // 32), jnp.uint32)
+        stuck = (s0 & jax.random.bits(k_q, s0.shape, jnp.uint32), s1 & ~s0)
+        qmask = jnp.arange(n_core) == 1
+    if rep == "sparse":
+        q_in = sparse.sparsify(bits, cfg.k_max)
+        bits = sparse.densify(q_in, d)
+    else:
+        q_in = hv.pack(bits) if rep == "packed" else bits
+    want = _whole_store_slot_search(cfg, hv.pack(bits), store, rows, qmask,
+                                    stuck)
+    held = hv.unpack(store, d) if rep == "unpacked" else store
+    got = scaleout._shard_top1_slots(cfg, n_core, 0, q_in, held, rows, qmask,
+                                     stuck)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 @pytest.mark.parametrize("rep", ["unpacked", "packed"])

@@ -278,3 +278,59 @@ def test_four_chip_ring_step_fits_each_v5e(topo, monkeypatch):
     reduce_ops = re.findall(r"= \S+ all-reduce\(.*", text)
     assert reduce_ops and all('ota_bundle/vote_exchange/' in op
                               for op in reduce_ops)
+
+
+@pytest.mark.parametrize("cell,classes,dim,cores,chips,slots", [
+    ("whype", C, D, RX, 1, SLOTS),
+    ("whype4", C, D, RX, 4, 8),
+    ("table1", 6400, 512, 64, 1, 32),
+])
+def test_ring_step_copies_only_the_searched_tenants(topo, monkeypatch, cell,
+                                                     classes, dim, cores,
+                                                     chips, slots):
+    """The benchmark's ring steps (64 tenants resident; ``whype4`` per chip's
+    class shard) take the slots' tenant rows from the store before any
+    relayout: apart from the store parameter no instruction holds the
+    store's shape, its [T * cores, C_core, W] bank table, or any other array
+    over all T tenants at the store's word width (a gather the compiler
+    splits into whole-store class chunks would), and the step's temporaries
+    stay under 2 GB."""
+    from repro.core import scaleout
+    from repro.kernels import common
+
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
+    tenants, w = 64, dim // 32
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                ("data", "model"))
+    cfg = scaleout.ScaleOutConfig(
+        n_classes=classes, dim=dim, m_tx=3, n_rx_cores=cores,
+        batch=RING_TRIALS if cell != "table1" else 64,
+        representation="packed", collective="psum_packed", channel="bsc",
+        noise="bitplane")
+    sds, state, e_per = _serve_structs(mesh, cfg)
+
+    def sharded(shape, dt, spec):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+
+    compiled = scaleout.make_mt_ota_serve(mesh, cfg).lower(
+        sharded((tenants, classes, w), jnp.uint32, P(None, "model", None)),
+        sharded((slots, cfg.batch, chips, e_per, w), jnp.uint32,
+                P(None, None, "model", None, None)),
+        sds((slots,), jnp.int32), state, sds((slots, 2), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert re.search(rf"u32\[{tenants},{classes // chips},{w}\]\S* parameter\(",
+                     text)
+    whole = []
+    for name, out, op in re.findall(r"%(\S+) = (\(.*?\)|\S+) ([\w-]+)\(", text):
+        for dims in re.findall(r"u32\[([\d,]+)\]", out):
+            shape = tuple(int(x) for x in dims.split(","))
+            if op != "parameter" and (
+                    shape[:1] == (tenants * cores // chips,)
+                    or (len(shape) == 3 and shape[0] == tenants
+                        and shape[2] == w)):
+                whole.append(f"{name} = {op} u32[{dims}]")
+    assert not whole, whole
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2e9, mem.temp_size_in_bytes
+    print(f"{cell} ring step: {mem.temp_size_in_bytes} bytes of temporaries")
